@@ -1,9 +1,13 @@
 """Kernel micro-benchmarks (beyond paper): worker-task GEMM + encode.
 
-CPU timings of the jnp oracle path (the Pallas kernels target TPU and are
-validated under interpret=True — wall-clock there measures the interpreter,
-not the kernel).  Derived column reports achieved GFLOP/s and the coded
-overhead factor N/K the paper's redundancy implies.
+It always times the jnp oracles (``coded_matmul_ref``, ``poly_encode_ref``),
+never the Pallas kernels, on whatever platform jax's default backend is:
+on a CPU host these are XLA:CPU timings, not device metrics.  The served
+path runs the Pallas kernel only on a TPU (``kernels/coded_matmul/ops.py``
+``implementation``); elsewhere it too runs the jnp oracle, and the CPU
+tests check the kernels under ``interpret=True``.  The derived column
+reports achieved GFLOP/s and the coded overhead factor N/K the paper's
+redundancy implies.
 """
 from __future__ import annotations
 

@@ -43,7 +43,7 @@ from dataclasses import dataclass, field
 
 from ..obs import NULL_REGISTRY
 from .transport import OperandHandle, Transport, make_transport
-from .worker import ChaosSpec, ComputeSpec, worker_main
+from .worker import START_FAILED, ChaosSpec, ComputeSpec, worker_main
 
 __all__ = ["WorkerPool", "WorkerHandle"]
 
@@ -282,13 +282,25 @@ class WorkerPool:
         Dead *backup* workers are scrapped without replacement (and without
         counting ``shards_lost`` — their copies are duplicates whose primary
         may still deliver); the dispatch decides whether the shard needs a
-        fresh copy.
+        fresh copy.  An active worker that exited because its shard computer
+        could not start raises ``RuntimeError`` instead.
         """
         self._check_open()
         dead = []
         for wid, h in list(self._active.items()):
             if h.alive():
                 continue
+            if not h.ready:
+                h.proc.join(_JOIN_TIMEOUT)    # its channel closes first
+            if h.proc.exitcode == START_FAILED:
+                # a replacement would fail the same way
+                raise RuntimeError(
+                    f"worker {wid} could not start its shard computer (its "
+                    "traceback is on stderr).  With --compute device each "
+                    "worker process needs a JAX device of its own, and a "
+                    "TPU host lets one JAX process hold its chips: one JAX "
+                    "process per chip.  Serve on the chip with --backend "
+                    "device.")
             dead.append((wid, set(h.busy)))
             self._bump("crashed")
             self._bump("shards_lost", len(h.busy))

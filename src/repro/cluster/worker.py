@@ -49,6 +49,7 @@ from __future__ import annotations
 import os
 import sys
 import time
+import traceback
 from dataclasses import dataclass, replace
 
 import numpy as np
@@ -58,9 +59,13 @@ from .config import global_config
 
 __all__ = ["ChaosSpec", "WorkerPlan", "ShardComputer", "NumpyShardComputer",
            "DeviceShardComputer", "ComputeSpec", "COMPUTE_NAMES",
-           "make_computer", "worker_main"]
+           "START_FAILED", "make_computer", "worker_main"]
 
 _HANG_SECONDS = 1e6
+
+# exit code of a worker whose shard computer could not start (the pool
+# turns it into an error instead of waiting for a handshake)
+START_FAILED = 3
 
 COMPUTE_NAMES = ("numpy", "device")
 
@@ -262,6 +267,15 @@ class DeviceShardComputer(ShardComputer):
         self._products = worker_products
         self._products_complex = worker_products_complex
         devices = jax.devices()
+        # An accelerator whose backend failed to start (on a TPU host: the
+        # chip is held by another process) is skipped quietly and jax falls
+        # back to the host CPU.  A device worker must not serve from there.
+        from jax._src import xla_bridge
+        if devices[0].platform == "cpu" and xla_bridge._backend_errors:
+            raise RuntimeError(
+                f"jax fell back to the CPU because an accelerator failed "
+                f"to start: {xla_bridge._backend_errors}.  Set "
+                f"JAX_PLATFORMS=cpu to serve from the CPU on purpose.")
         self.device = devices[int(device_index) % len(devices)]
         self.use_pallas = use_pallas
         self.dtype = jnp.dtype(dtype)
@@ -332,11 +346,19 @@ def worker_main(worker_id: int, endpoint_arg, plan: WorkerPlan,
     except TransportClosed:
         return                                   # master already gone
     try:
-        computer = make_computer(compute)
-        computer.warmup()                        # jax init before the ready
-        try:                                     # handshake: lease() blocks
-            endpoint.send(("ready", int(worker_id)))  # on this, so dispatch
-        except TransportClosed:                  # never pays for startup
+        # jax init before the ready handshake: lease() blocks on it, so
+        # dispatch never pays for startup.  A computer that cannot start
+        # exits with START_FAILED, which the pool reports instead of
+        # respawning a worker that would fail the same way.
+        try:
+            computer = make_computer(compute)
+            computer.warmup()
+        except Exception:
+            traceback.print_exc()
+            sys.exit(START_FAILED)
+        try:
+            endpoint.send(("ready", int(worker_id)))
+        except TransportClosed:
             return
         first_task = True
         while True:

@@ -337,8 +337,8 @@ class SimulationEngine:
         """Scoped x64 mode so the engine gets f64 fidelity without flipping
         global jax config for the rest of the process."""
         if self.jax_x64:
-            from jax.experimental import enable_x64
-            return enable_x64()
+            import jax
+            return jax.enable_x64(True)
         import contextlib
         return contextlib.nullcontext()
 

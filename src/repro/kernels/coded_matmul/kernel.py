@@ -36,9 +36,15 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_z: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # (1, bm, bz) x (1, bz, bn) -> accumulate (bm, bn) in f32 on the MXU
+    # (1, bm, bz) x (1, bz, bn) -> accumulate (bm, bn) in f32 on the MXU.
+    # f32 operands need HIGHEST: Mosaic's default multiplies them at about
+    # bf16 precision on a v5e (products off by ~2e-3 in norm), and the SAC
+    # decode amplifies worker error through its Vandermonde solve.  Mosaic
+    # refuses HIGHEST for bf16 operands, which need nothing more.
+    f32 = a_ref.dtype == jnp.float32
     acc_ref[...] += jax.lax.dot_general(
         a_ref[0], b_ref[0], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
         preferred_element_type=jnp.float32)
 
     @pl.when(z == n_z - 1)
@@ -79,7 +85,9 @@ def coded_matmul_pallas(E_A: jax.Array, E_B: jax.Array, *, bm: int = 256,
             pl.BlockSpec((1, bz, bn), lambda w, i, j, z: (w, z, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda w, i, j, z: (w, i, j)),
-        out_shape=jax.ShapeDtypeStruct((W, M, N), out_dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (W, M, N), out_dtype,
+            vma=jax.typeof(E_A).vma | jax.typeof(E_B).vma),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(E_A, E_B)

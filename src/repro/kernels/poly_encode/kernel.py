@@ -7,7 +7,9 @@ per block element), so the kernel is tiled for streaming:
 
 * grid ``(W, R/br, C/bc, K)`` — contraction (k) innermost, f32 accumulator
   resident in VMEM across k steps.
-* the generator coefficient is a (1,1) block prefetched to SMEM; the block
+* the whole generator ``G`` sits in SMEM (N·K scalars) and each step reads
+  its coefficient ``G[w, k]``; a (1, 1) block of it is refused by the TPU
+  lowering, whose blocks must be (8, 128)-aligned or span the array.  The
   tile multiply-add runs on the VPU (not a matmul shape — broadcast scalar).
 * tiles default to (256, 256): 256 KB/input tile, double-buffered.
 """
@@ -24,13 +26,13 @@ __all__ = ["poly_encode_pallas"]
 
 
 def _encode_kernel(g_ref, x_ref, o_ref, acc_ref, *, n_k: int):
-    k = pl.program_id(3)
+    w, k = pl.program_id(0), pl.program_id(3)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += g_ref[0, 0] * x_ref[0].astype(jnp.float32)
+    acc_ref[...] += g_ref[w, k] * x_ref[0].astype(jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -51,8 +53,7 @@ def poly_encode_pallas(G: jax.Array, X: jax.Array, *, br: int = 256,
         functools.partial(_encode_kernel, n_k=K),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda w, i, j, k: (w, k),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, br, bc), lambda w, i, j, k: (k, i, j)),
         ],
         out_specs=pl.BlockSpec((1, br, bc), lambda w, i, j, k: (w, i, j)),

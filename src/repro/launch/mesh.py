@@ -6,9 +6,16 @@ chips with a leading "pod" axis (pure DP over DCN).
 """
 from __future__ import annotations
 
-from ..compat import make_mesh
+import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh"]
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the models' sharding hints and
+    GSPMD placement assume them (``jax.make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -471,6 +471,11 @@ def _effective_config(args, deadlines) -> str:
                    compute=args.compute, transport=args.transport)
     if args.replay is not None:
         cfg.update(compute=args.compute)
+    elif args.backend == "device":
+        # which implementation runs the products, and on what: the jnp
+        # oracle stands in for Pallas off the TPU, and says so here
+        from repro.serving.backends import device_info
+        cfg.update(device_info())
     if args.speculate:
         cfg.update(hedge_threshold=args.hedge_threshold,
                    max_speculations=args.max_speculations,
@@ -980,6 +985,8 @@ def main(argv=None):
     if problems:
         raise SystemExit("[serve] invalid arguments:\n  " +
                          "\n  ".join(problems))
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if not args.json:
         deadlines = tuple(float(x) for x in args.deadlines.split(","))
         print(f"[serve] config {_effective_config(args, deadlines)}")

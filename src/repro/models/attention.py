@@ -13,7 +13,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import pvary, shard_map
 from .hints import axes_hint, batch_hint, get_model_info
 
 __all__ = ["blockwise_attention", "decode_attention", "KVCache"]
@@ -134,10 +133,13 @@ def _smap_attention(q, k, v, mesh, *, causal, window, q_offset, bkv):
                                    kv_len=Lkv), None
 
             axes = tuple(mesh.axis_names)
-            m0 = pvary(jnp.full((Bl, H, bq, 1), NEG_INF,
-                                        jnp.float32), axes)
-            l0 = pvary(jnp.zeros((Bl, H, bq, 1), jnp.float32), axes)
-            a0 = pvary(jnp.zeros((Bl, H, bq, d), jnp.float32), axes)
+            m0 = jax.lax.pcast(
+                jnp.full((Bl, H, bq, 1), NEG_INF, jnp.float32), axes,
+                to="varying")
+            l0 = jax.lax.pcast(jnp.zeros((Bl, H, bq, 1), jnp.float32), axes,
+                               to="varying")
+            a0 = jax.lax.pcast(jnp.zeros((Bl, H, bq, d), jnp.float32), axes,
+                               to="varying")
             (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0),
                                           jnp.arange(nkv))
             outs.append((acc / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype))
@@ -145,7 +147,7 @@ def _smap_attention(q, k, v, mesh, *, causal, window, q_offset, bkv):
 
     win_arr = window if isinstance(window, jax.Array) else \
         jnp.asarray(window if window else 0, jnp.int32)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, "model", None, None),
                   P(bspec, None, None, None),

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from .layers import init_dense
 
 __all__ = ["init_moe_params", "moe_block", "moe_ref", "router_aux_loss"]
@@ -197,7 +196,7 @@ def moe_block(p: dict, x: jax.Array, cfg):
         aux = jax.lax.pmean(aux, baxes) if baxes else aux
         return out, aux
 
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(P(tok_spec, None), w_specs),
                        out_specs=(P(tok_spec, None), P()))
     return fn(x, p)

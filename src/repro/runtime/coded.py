@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
-
 from ..core.codes.base import CDCCode
 from ..kernels.coded_matmul.ops import worker_products
 
@@ -105,8 +103,7 @@ def distributed_coded_matmul(E_A, E_B, weights, mesh: Mesh,
         return jax.lax.psum(contrib, axis)     # decode == weighted reduction
 
     spec = P(axis)
-    fn = shard_map(worker, mesh=mesh,
-                       in_specs=(spec, spec, spec),
+    fn = jax.shard_map(worker, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=P())
     return fn(E_A, E_B, weights)
 

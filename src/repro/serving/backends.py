@@ -41,7 +41,7 @@ from ..core.straggler import (sample_times, shifted_exp_times,
                               validate_latency_kw)
 
 __all__ = ["ExecutionBackend", "SyntheticDispatch", "SimulatedBackend",
-           "DeviceBackend", "make_backend", "BACKEND_NAMES"]
+           "DeviceBackend", "device_info", "make_backend", "BACKEND_NAMES"]
 
 
 class SyntheticDispatch:
@@ -201,6 +201,11 @@ class DeviceBackend(ExecutionBackend):
     The batch and worker axes fold into the kernel's single worker dim
     (``(B·N, Nx, bz) @ (B·N, bz, Ny)``) so one launch covers the whole batch.
     Latencies reuse the simulated model (see module docstring).
+
+    ``info`` records what runs the products: the implementation
+    (``"pallas"`` or the ``"jnp"`` oracle, see
+    :func:`~repro.kernels.coded_matmul.ops.implementation`) and the
+    device's platform, kind and count, as :func:`device_info` reports them.
     """
 
     name = "device"
@@ -210,7 +215,8 @@ class DeviceBackend(ExecutionBackend):
                  straggler_frac: float = 0.0,
                  straggler_slowdown: float = 5.0):
         import jax.numpy as jnp
-        self.use_pallas = use_pallas
+        self.info = device_info(use_pallas)
+        self.use_pallas = self.info["impl"] == "pallas"
         self.dtype = jnp.float32 if dtype is None else dtype
         self.latency_kw = {"shift": shift, "rate": rate,
                            "straggler_frac": straggler_frac,
@@ -270,6 +276,18 @@ class DeviceBackend(ExecutionBackend):
             jnp.asarray(E_A, dt), jnp.asarray(E_B, dt),
             jnp.asarray(np.asarray(weights), dt), mesh, axis=axis,
             use_pallas=use_pallas)
+
+
+def device_info(use_pallas: bool | None = None) -> dict:
+    """The worker-product implementation and the jax device it runs on."""
+    import jax
+
+    from ..kernels.coded_matmul.ops import implementation
+    devices = jax.devices()
+    return {"impl": implementation(use_pallas),
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
 
 
 def _make_cluster(**kw):

@@ -530,6 +530,20 @@ def test_device_compute_serve_and_replay_bit_identity():
     assert time.monotonic() - t0 < 120.0
 
 
+def test_device_worker_start_failure_is_a_prompt_error():
+    """A device worker that cannot start its computer (on a TPU host: a
+    second process asking for a held chip) fails the lease at once, naming
+    the cause, instead of waiting out ``ready_timeout`` or losing shards."""
+    from repro.cluster import ComputeSpec
+    broken = ComputeSpec(kind="device", dtype="no-such-dtype")
+    t0 = time.monotonic()
+    with WorkerPool(0, seed=0, compute=broken, ready_timeout=60.0) as pool:
+        with pytest.raises(RuntimeError,
+                           match="one JAX process per chip.*--backend device"):
+            pool.lease(2)
+    assert time.monotonic() - t0 < 30.0
+
+
 def test_transport_releases_operands_on_crash_and_teardown():
     """Published operand blocks never outlive their dispatch: the worker
     endpoint closes its shm attachments on every exit path (even a crash

@@ -104,7 +104,7 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.runtime.coded import (distributed_coded_matmul,
                                      decode_weight_vector, encode_operands)
     from repro.core.partition import split_contraction
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     K, N = 3, 8

@@ -451,7 +451,7 @@ def test_device_backend_complex_reim_expansion():
 def test_device_decode_on_mesh_exact():
     import jax
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.serving import DeviceBackend
     if len(jax.devices()) < 1:
         pytest.skip("no jax device")
@@ -468,3 +468,65 @@ def test_device_decode_on_mesh_exact():
                                        use_pallas=False)
     rel = np.linalg.norm(np.asarray(est) - A @ B) / np.linalg.norm(A @ B)
     assert rel < 1e-3
+
+
+def test_device_serve_report_names_platform_and_implementation(capsys):
+    """Off the TPU the jnp oracle stands in for the Pallas kernel; the
+    report and the ``[serve] config`` line say so."""
+    import json
+
+    from repro.launch.serve import build_parser, main, run_serve
+    argv = ["--backend", "device", "--code", "matdot", "--K", "2", "--N",
+            "4", "--requests", "2", "--rows", "8", "--inner", "16"]
+    rep = run_serve(build_parser().parse_args(argv))
+    assert rep.config["platform"] == "cpu"
+    assert rep.config["impl"] == "jnp"
+    assert rep.config["device_kind"] and rep.config["device_count"] >= 1
+    main(argv)
+    line = next(ln for ln in capsys.readouterr().out.splitlines()
+                if ln.startswith("[serve] config "))
+    cfg = json.loads(line[len("[serve] config "):])
+    assert (cfg["platform"], cfg["impl"]) == ("cpu", "jnp")
+
+
+@pytest.mark.parametrize("flags", [[], ["--four-chips"]],
+                         ids=["one_chip", "four_chips"])
+def test_chip_smoke_refuses_a_cpu_backend(flags):
+    """The smoke never carries on without a TPU: it fails in its device
+    phase and prints no ``"ok": true`` line."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    res = subprocess.run([sys.executable, "chip_smoke.py", *flags],
+                         cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, text=True, timeout=120)
+    assert res.returncode != 0
+    assert "not a TPU" in res.stderr
+    lines = res.stdout.strip().splitlines()
+    assert json.loads(lines[0])["platform"] == "cpu"
+    assert json.loads(lines[-1]).get("ok") is not True
+
+
+def test_compile_cache_respects_env_and_otherwise_uses_checkout(
+        monkeypatch, tmp_path):
+    import os
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before   # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        path = enable_compile_cache()
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
