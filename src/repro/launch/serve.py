@@ -619,8 +619,10 @@ def run_serve(args) -> ServeReport:
         except ValueError as e:
             raise SystemExit(f"[serve] invalid arguments:\n  {e}")
     else:
+        # the device backend's phase spans and byte counters
+        obs_kw = {"metrics": registry} if args.backend == "device" else {}
         backend = make_backend(args.backend,
-                               straggler_frac=args.straggler_frac)
+                               straggler_frac=args.straggler_frac, **obs_kw)
     cfg = ServeConfig(deadlines=deadlines, stream=args.stream,
                       batch_size=args.batch_size, beta_mode=args.beta,
                       decoder=args.decoder, seed=args.seed)

@@ -5,7 +5,8 @@ finished when, and what the completion bought.  This package makes that
 observable on the live runtime instead of reconstructable from print lines:
 
 * :class:`MetricsRegistry` — named counters / gauges / histograms threaded
-  through the pool, transport, backend, scheduler and decode cache; a
+  through the pool, transport, backend, scheduler and decode cache, and
+  ``span`` blocks that time a phase and annotate the profiler's trace; a
   disabled registry hands out shared no-op instruments so the hot path
   pays one attribute call when observability is off.
 * :class:`Tracer` — per-shard spans assembled master-side from worker-

@@ -36,6 +36,7 @@ import numpy as np
 from ..cluster.events import ShardEvent
 from ..core.codes.base import CDCCode
 from ..names import unknown_name
+from ..obs import NULL_REGISTRY
 from ..core.partition import split_contraction
 from ..core.straggler import (sample_times, shifted_exp_times,
                               validate_latency_kw)
@@ -206,6 +207,8 @@ class DeviceBackend(ExecutionBackend):
     (``"pallas"`` or the ``"jnp"`` oracle, see
     :func:`~repro.kernels.coded_matmul.ops.implementation`) and the
     device's platform, kind and count, as :func:`device_info` reports them.
+    ``metrics`` (a :class:`~repro.obs.MetricsRegistry`) receives the phase
+    spans and byte counters of :meth:`compute_products`.
     """
 
     name = "device"
@@ -213,7 +216,7 @@ class DeviceBackend(ExecutionBackend):
     def __init__(self, *, use_pallas: bool | None = None,
                  dtype=None, shift: float = 1.0, rate: float = 1.0,
                  straggler_frac: float = 0.0,
-                 straggler_slowdown: float = 5.0):
+                 straggler_slowdown: float = 5.0, metrics=None):
         import jax.numpy as jnp
         self.info = device_info(use_pallas)
         self.use_pallas = self.info["impl"] == "pallas"
@@ -221,30 +224,50 @@ class DeviceBackend(ExecutionBackend):
         self.latency_kw = {"shift": shift, "rate": rate,
                            "straggler_frac": straggler_frac,
                            "straggler_slowdown": straggler_slowdown}
+        self.metrics = metrics if metrics is not None else NULL_REGISTRY
+        self._c_h2d = self.metrics.counter("serve.h2d_bytes")
+        self._c_d2h = self.metrics.counter("serve.d2h_bytes")
 
     def compute_products(self, code: CDCCode, As, Bs,
                          n_shards: int | None = None) -> np.ndarray:
-        import jax.numpy as jnp
+        """Encode on the host, then cast, copy in, multiply, copy back.
+
+        Each phase is a ``serve.*`` span of the registry; ``serve.products``
+        covers all five and the release of their temporaries, and
+        ``serve.h2d_bytes`` / ``serve.d2h_bytes`` count the bytes copied
+        each way.
+        """
+        with self.metrics.span("serve.products"):
+            return self._phased_products(code, As, Bs, n_shards)
+
+    def _phased_products(self, code, As, Bs, n_shards):
+        import jax
 
         from ..kernels.coded_matmul.ops import (worker_products,
                                                 worker_products_complex)
-        E_A, E_B = self._encode_batch(code, As, Bs, n_shards)
+        span = self.metrics.span
+        with span("serve.encode"):
+            E_A, E_B = self._encode_batch(code, As, Bs, n_shards)
         B, N = E_A.shape[:2]
         ea = E_A.reshape((B * N,) + E_A.shape[2:])
         eb = E_B.reshape((B * N,) + E_B.shape[2:])
-        if np.iscomplexobj(ea) or np.iscomplexobj(eb):
-            # the paper's 4× real-multiply expansion — no complex on device
-            re, im = worker_products_complex(
-                jnp.asarray(ea.real, self.dtype),
-                jnp.asarray(ea.imag, self.dtype),
-                jnp.asarray(eb.real, self.dtype),
-                jnp.asarray(eb.imag, self.dtype),
-                use_pallas=self.use_pallas)
-            P = np.asarray(re) + 1j * np.asarray(im)
-        else:
-            P = np.asarray(worker_products(jnp.asarray(ea, self.dtype),
-                                           jnp.asarray(eb, self.dtype),
-                                           use_pallas=self.use_pallas))
+        # the paper's 4× real-multiply expansion — no complex on device
+        cplx = np.iscomplexobj(ea) or np.iscomplexobj(eb)
+        with span("serve.cast"):
+            host = [np.asarray(x, dtype=self.dtype) for x in (
+                (ea.real, ea.imag, eb.real, eb.imag) if cplx else (ea, eb))]
+        with span("serve.h2d"):
+            dev = jax.device_put(host)
+        self._c_h2d.inc(sum(x.nbytes for x in host))
+        with span("serve.device_wait"):
+            pallas = self.use_pallas
+            out = worker_products_complex(*dev, use_pallas=pallas) if cplx \
+                else [worker_products(*dev, use_pallas=pallas)]
+            jax.block_until_ready(out)
+        with span("serve.d2h"):
+            out = [np.asarray(x) for x in out]
+            P = out[0] + 1j * out[1] if cplx else out[0]
+        self._c_d2h.inc(sum(x.nbytes for x in out))
         return P.reshape((B, N) + P.shape[1:])
 
     def draw_latencies(self, rng: np.random.Generator,

@@ -195,17 +195,14 @@ class MasterScheduler:
         self.burn = burn if burn is not None else NULL_BURN
         if self.flight.enabled and self.sampler.enabled:
             self.flight.bind_sampler(self.sampler)
-        # gate perf_counter pairs (a real cost even when discarded) on one
-        # bool instead of the registry's no-op instruments
+        # gate per-request histogram loops on one bool instead of the
+        # registry's no-op instruments
         self._m_on = self.metrics.enabled
         self._g_queue = self.metrics.gauge("serve.queue_depth")
         self._g_inflight = self.metrics.gauge("serve.inflight_shards")
         self._g_err = self.metrics.gauge("serve.last_rel_err")
-        self._h_tick = self.metrics.histogram("serve.decode_tick_seconds")
         self._h_ttfa = self.metrics.histogram("serve.tta_first_seconds")
         self._h_tta = self.metrics.histogram("serve.tta_exact_seconds")
-        self._h_depth = self.metrics.histogram("serve.queue_depth_sampled")
-        self._h_decode = self.metrics.histogram("serve.decode_push_seconds")
         self._c_shed = self.metrics.counter("serve.shed")
         # global serve clock for closed-loop telemetry: accumulated batch
         # spans, so sampler ticks share one timeline with open-loop runs
@@ -232,7 +229,7 @@ class MasterScheduler:
         self._batches_served = 0
         # open-loop admission bookkeeping: shed decisions and the queue-depth
         # time series ((t, depth) samples at every admission/dispatch on the
-        # global serve clock — the registry's histogram mirrors the depths)
+        # global serve clock)
         self.shed: list[tuple[str, float]] = []        # (tenant, arrival)
         self.depth_series: list[tuple[float, int]] = []
         # hedge-trigger observation window: recent per-batch completion rows
@@ -289,7 +286,6 @@ class MasterScheduler:
             deadline=None if deadline is None else float(deadline),
             target=None if target is None else float(target)))
         self._g_queue.set(len(self._queue))
-        self._h_depth.observe(float(len(self._queue)))
         self.depth_series.append((float(arrival), len(self._queue)))
         return req_id
 
@@ -600,17 +596,18 @@ class MasterScheduler:
         # closed-form modes don't, so skip the K block matmuls for them
         needs_oracle = cfg.beta_mode == "oracle"
         refs = []
-        for r in batch:
-            C = norm = req_oracle = None
-            if cfg.track_errors:
-                C = r.A @ r.B
-                norm = float(np.linalg.norm(C) ** 2)
-            if needs_oracle:
-                from ..core.partition import split_contraction
-                Ab, Bb = split_contraction(np.asarray(r.A), np.asarray(r.B),
-                                           code.K)
-                req_oracle = code.oracle_context(Ab, Bb)
-            refs.append((C, norm, req_oracle))
+        with self.metrics.span("serve.oracle"):
+            for r in batch:
+                C = norm = req_oracle = None
+                if cfg.track_errors:
+                    C = r.A @ r.B
+                    norm = float(np.linalg.norm(C) ** 2)
+                if needs_oracle:
+                    from ..core.partition import split_contraction
+                    Ab, Bb = split_contraction(np.asarray(r.A),
+                                               np.asarray(r.B), code.K)
+                    req_oracle = code.oracle_context(Ab, Bb)
+                refs.append((C, norm, req_oracle))
         cache = self._cache_for(batch)
         decoders = [make_decoder(cfg.decoder, code, beta_mode=cfg.beta_mode,
                                  oracle=refs[i][2], cache=cache)
@@ -630,18 +627,20 @@ class MasterScheduler:
     def _open_track(self, batch, decoders, refs, results, m: int, R: int,
                     t_glob: float) -> None:
         """Stamp ``t_target`` for requests whose accuracy SLO was just met."""
-        for r, dec, (C, norm, _), res in zip(batch, decoders, refs, results):
-            if r.target is None or res.t_target is not None:
-                continue
-            if m >= R:                     # exact: every target is met
-                res.t_target = t_glob
-                continue
-            est = dec.estimate()
-            if est is None or C is None or norm <= 0.0:
-                continue
-            err = float(np.linalg.norm(est - C) ** 2 / norm)
-            if err <= r.target:
-                res.t_target = t_glob
+        with self.metrics.span("serve.oracle"):
+            for r, dec, (C, norm, _), res in zip(batch, decoders, refs,
+                                                 results):
+                if r.target is None or res.t_target is not None:
+                    continue
+                if m >= R:                 # exact: every target is met
+                    res.t_target = t_glob
+                    continue
+                est = dec.estimate()
+                if est is None or C is None or norm <= 0.0:
+                    continue
+                err = float(np.linalg.norm(est - C) ** 2 / norm)
+                if err <= r.target:
+                    res.t_target = t_glob
 
     @staticmethod
     def _open_settled(batch, results, m: int, R: int) -> bool:
@@ -653,6 +652,13 @@ class MasterScheduler:
 
     def _serve_batch(self, batch: list[MatmulRequest],
                      cls=None, open_ctx=None) -> list[RequestResult]:
+        """:meth:`_walk_batch` inside the batch's ``serve.batch`` span."""
+        with self.metrics.span("serve.batch", batch=self._batches_served,
+                               requests=len(batch)):
+            return self._walk_batch(batch, cls, open_ctx)
+
+    def _walk_batch(self, batch: list[MatmulRequest],
+                    cls=None, open_ctx=None) -> list[RequestResult]:
         """THE event loop: every backend serves through this one code path.
 
         The backend's ``dispatch_batch`` handle yields ``done`` / ``lost``
@@ -789,18 +795,11 @@ class MasterScheduler:
                         start=disp_t.get(ev.shard, 0.0) if spec else 0.0,
                         timings=getattr(ev, "timings", None),
                         speculative=spec)
-                    if self._m_on:
-                        d0 = time.perf_counter()
+                    with self.metrics.span("serve.decode_push") as sp:
                         for i, dec in enumerate(decoders):
                             dec.push(ev.shard, ev.products[i])
-                        d_dur = time.perf_counter() - d0
-                        self._h_decode.observe(d_dur)
-                        self.tracer.decode_apply(bid, ev.shard, ev.t,
-                                                 dur=d_dur)
-                    else:
-                        for i, dec in enumerate(decoders):
-                            dec.push(ev.shard, ev.products[i])
-                        self.tracer.decode_apply(bid, ev.shard, ev.t)
+                    self.tracer.decode_apply(bid, ev.shard, ev.t,
+                                             dur=sp.seconds)
                     shard_times[ev.shard] = ev.t
                     self.flight.record("done", batch=bid, shard=ev.shard,
                                        worker=ev.worker, t=ev.t, m=m)
@@ -955,20 +954,18 @@ class MasterScheduler:
 
     def _emit(self, batch, decoders, refs, results, t, m, R, kind,
               bid: int = 0) -> None:
-        t0 = time.perf_counter() if self._m_on else 0.0
         errs = []
-        for dec, (C, norm, _), res in zip(decoders, refs, results):
-            est = dec.estimate()
-            err = None
-            if est is not None and C is not None and norm > 0.0:
-                err = float(np.linalg.norm(est - C) ** 2 / norm)
-                errs.append(err)
-            res.answers.append(Answer(t=t, m=m, rel_err=err,
-                                      exact=m >= R, kind=kind))
-        if self._m_on:
-            self._h_tick.observe(time.perf_counter() - t0)
-            if errs:           # the sampler's anytime-accuracy trajectory
-                self._g_err.set(sum(errs) / len(errs))
+        with self.metrics.span("serve.decode_tick"):
+            for dec, (C, norm, _), res in zip(decoders, refs, results):
+                est = dec.estimate()
+                err = None
+                if est is not None and C is not None and norm > 0.0:
+                    err = float(np.linalg.norm(est - C) ** 2 / norm)
+                    errs.append(err)
+                res.answers.append(Answer(t=t, m=m, rel_err=err,
+                                          exact=m >= R, kind=kind))
+        if errs:               # the sampler's anytime-accuracy trajectory
+            self._g_err.set(sum(errs) / len(errs))
         if kind == "deadline":
             self.tracer.milestone(bid, "deadline-tick", t, m=m)
 

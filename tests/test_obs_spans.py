@@ -1,0 +1,247 @@
+"""Phase spans of the registry inside the device backend and the scheduler.
+
+``MetricsRegistry.span`` times a block into ``<name>_seconds`` and opens a
+profiler annotation of the same name; a disabled registry hands out one
+shared no-op span.  The device backend splits its products into encode,
+cast, copy in, device wait and copy back, and counts the bytes copied each
+way; the scheduler wraps each batch, the accuracy-SLO oracle and the decode
+pushes and ticks.  None of it may change a served number.
+"""
+import glob
+import time
+
+import numpy as np
+import pytest
+
+from repro.core import GroupSACCode, x_complex
+from repro.core.registry import make_code
+from repro.obs import NULL_REGISTRY, MetricsRegistry
+from repro.serving import (DeviceBackend, MasterScheduler, ServeConfig,
+                           SimulatedBackend)
+
+ROWS, INNER, COLS = 8, 16, 6
+# direct children of serve.batch: what is left is the scheduler's own work
+BATCH_CHILDREN = ("serve.products", "serve.oracle", "serve.decode_push",
+                  "serve.decode_tick")
+PRODUCT_PHASES = ("serve.encode", "serve.cast", "serve.h2d",
+                  "serve.device_wait", "serve.d2h")
+
+
+def _codes():
+    return {"real": make_code("layer_sac_ortho", 4, 8),
+            "complex": GroupSACCode(4, 8, x_complex(8, 0.1), [2, 2])}
+
+
+def _operands(n, seed=0):
+    rng = np.random.default_rng(seed)
+    return ([rng.standard_normal((ROWS, INNER)) for _ in range(n)],
+            [rng.standard_normal((INNER, COLS)) for _ in range(n)])
+
+
+def _totals(reg):
+    return {k: v["total"] for k, v in reg.snapshot()["histograms"].items()}
+
+
+# --------------------------------------------------------------- the helper
+
+def test_enabled_span_records_histogram_and_seconds():
+    reg = MetricsRegistry()
+    with reg.span("serve.phase", batch=3) as sp:
+        time.sleep(0.002)
+    h = reg.snapshot()["histograms"]["serve.phase_seconds"]
+    assert h["count"] == 1
+    assert sp.seconds >= 0.002
+    assert h["total"] == sp.seconds
+    with reg.span("serve.phase"):
+        pass
+    assert reg.histogram("serve.phase_seconds").count == 2
+
+
+def test_null_span_is_shared_and_reads_no_clock(monkeypatch):
+    calls = []
+
+    def clock():
+        calls.append(1)
+        return 0.0
+
+    monkeypatch.setattr(time, "perf_counter", clock)
+    sp = NULL_REGISTRY.span("serve.batch", batch=1, requests=4)
+    assert sp is NULL_REGISTRY.span("serve.products")
+    assert sp is MetricsRegistry(enabled=False).span("serve.cast")
+    with sp as entered:
+        pass
+    assert entered is sp and sp.seconds is None
+    assert calls == []
+    assert NULL_REGISTRY.snapshot()["histograms"] == {}
+    with MetricsRegistry().span("serve.batch"):  # the clock it would read
+        pass
+    assert len(calls) == 2
+
+
+def test_span_does_not_swallow_exceptions():
+    reg = MetricsRegistry()
+    with pytest.raises(KeyError):
+        with reg.span("serve.phase"):
+            raise KeyError("x")
+    assert reg.histogram("serve.phase_seconds").count == 1
+
+
+# --------------------------------------------------------- the device backend
+
+def _products_before_spans(backend, code, As, Bs):
+    """The products as ``DeviceBackend`` formed them before the split of
+    the cast from the copy: ``jnp.asarray(x, dtype)`` did both."""
+    import jax.numpy as jnp
+
+    from repro.kernels.coded_matmul.ops import (worker_products,
+                                                worker_products_complex)
+    E_A, E_B = backend._encode_batch(code, As, Bs)
+    B, N = E_A.shape[:2]
+    ea = E_A.reshape((B * N,) + E_A.shape[2:])
+    eb = E_B.reshape((B * N,) + E_B.shape[2:])
+    if np.iscomplexobj(ea):
+        re, im = worker_products_complex(
+            jnp.asarray(ea.real, backend.dtype),
+            jnp.asarray(ea.imag, backend.dtype),
+            jnp.asarray(eb.real, backend.dtype),
+            jnp.asarray(eb.imag, backend.dtype), use_pallas=False)
+        P = np.asarray(re) + 1j * np.asarray(im)
+    else:
+        P = np.asarray(worker_products(jnp.asarray(ea, backend.dtype),
+                                       jnp.asarray(eb, backend.dtype),
+                                       use_pallas=False))
+    return P.reshape((B, N) + P.shape[1:])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["real", "complex"])
+def test_device_products_bit_identical_with_spans_on_and_off(kind, dtype):
+    import jax.numpy as jnp
+    code = _codes()[kind]
+    As, Bs = _operands(3, seed=7)
+    dt = getattr(jnp, dtype)
+    off = DeviceBackend(use_pallas=False, dtype=dt)
+    on = DeviceBackend(use_pallas=False, dtype=dt, metrics=MetricsRegistry())
+    got_off = off.compute_products(code, As, Bs)
+    got_on = on.compute_products(code, As, Bs)
+    before = _products_before_spans(off, code, As, Bs)
+    assert np.iscomplexobj(got_on) == (kind == "complex")
+    assert got_on.dtype == got_off.dtype == before.dtype
+    np.testing.assert_array_equal(got_on, got_off)
+    np.testing.assert_array_equal(got_on, before)
+
+
+@pytest.mark.parametrize("kind", ["real", "complex"])
+def test_device_byte_counters_follow_the_shapes(kind):
+    code = _codes()[kind]
+    n_req = 3
+    As, Bs = _operands(n_req, seed=1)
+    reg = MetricsRegistry()
+    DeviceBackend(use_pallas=False, metrics=reg).compute_products(
+        code, As, Bs)
+    parts = 2 if kind == "complex" else 1        # real and imaginary
+    W, bz, f32 = n_req * code.N, INNER // code.K, 4
+    counters = reg.snapshot()["counters"]
+    assert counters["serve.h2d_bytes"] == \
+        parts * W * (ROWS * bz + bz * COLS) * f32
+    assert counters["serve.d2h_bytes"] == parts * W * ROWS * COLS * f32
+
+
+def test_device_phases_nest_inside_products():
+    reg = MetricsRegistry()
+    As, Bs = _operands(2)
+    DeviceBackend(use_pallas=False, metrics=reg).compute_products(
+        _codes()["complex"], As, Bs)
+    hist = reg.snapshot()["histograms"]
+    assert all(hist[f"{n}_seconds"]["count"] == 1
+               for n in ("serve.products",) + PRODUCT_PHASES)
+    assert sum(hist[f"{n}_seconds"]["total"] for n in PRODUCT_PHASES) \
+        <= hist["serve.products_seconds"]["total"]
+
+
+# -------------------------------------------------------------- the scheduler
+
+def _serve(backend, reg, n_req, batch_size=2):
+    sched = MasterScheduler(_codes()["real"], backend,
+                            ServeConfig(batch_size=batch_size, seed=3),
+                            metrics=reg)
+    As, Bs = _operands(n_req, seed=5)
+    for A, B in zip(As, Bs):
+        sched.submit(A, B)
+    return sched.run()
+
+
+def test_scheduler_records_one_batch_span_per_batch():
+    reg = MetricsRegistry()
+    results = _serve(SimulatedBackend(), reg, n_req=5)
+    assert len(results) == 5
+    hist = reg.snapshot()["histograms"]
+    assert hist["serve.batch_seconds"]["count"] == 3          # 2 + 2 + 1
+    assert hist["serve.oracle_seconds"]["count"] >= 3
+    assert hist["serve.decode_push_seconds"]["count"] >= 3
+    assert hist["serve.decode_tick_seconds"]["count"] >= 3
+    assert "serve.queue_depth_sampled" not in hist
+
+
+def test_scheduler_self_time_is_never_negative():
+    reg = MetricsRegistry()
+    backend = DeviceBackend(use_pallas=False, metrics=reg)
+    for _ in range(3):
+        before = _totals(reg)
+        _serve(backend, reg, n_req=2)
+        after = _totals(reg)
+        delta = {k: after[k] - before.get(k, 0.0) for k in after}
+        children = sum(delta[f"{n}_seconds"] for n in BATCH_CHILDREN)
+        assert delta["serve.batch_seconds"] - children >= 0.0
+
+
+def test_served_answers_do_not_depend_on_the_registry():
+    def answers(reg):
+        return [[(a.t, a.m, a.rel_err) for a in r.answers]
+                for r in _serve(DeviceBackend(use_pallas=False, metrics=reg),
+                                reg, n_req=3)]
+    assert answers(MetricsRegistry()) == answers(NULL_REGISTRY)
+
+
+def test_spans_land_nested_on_the_profiler_trace(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+    reg = MetricsRegistry()
+    backend = DeviceBackend(use_pallas=False, metrics=reg)
+    _serve(backend, reg, n_req=2)                    # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    _serve(backend, reg, n_req=2)
+    jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    spans.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    assert set(spans) >= {"serve.batch", "serve.oracle", "serve.products",
+                          "serve.decode_push", "serve.decode_tick",
+                          *PRODUCT_PHASES}
+    (b0, b1), = spans["serve.batch"]
+    (p0, p1), = spans["serve.products"]
+    assert b0 <= p0 and p1 <= b1
+    for name in PRODUCT_PHASES:
+        (s, e), = spans[name]
+        assert p0 <= s and e <= p1
+
+
+def test_serve_cli_metrics_out_carries_device_phases(tmp_path):
+    import json
+
+    from repro.launch.serve import build_parser, run_serve
+    out = tmp_path / "metrics.json"
+    argv = ["--backend", "device", "--code", "matdot", "--K", "2", "--N",
+            "4", "--requests", "2", "--rows", "8", "--inner", "16",
+            "--metrics-out", str(out)]
+    run_serve(build_parser().parse_args(argv))
+    snap = json.loads(out.read_text())
+    assert snap["counters"]["serve.h2d_bytes"] > 0
+    assert snap["counters"]["serve.d2h_bytes"] > 0
+    for name in ("serve.batch", "serve.products") + PRODUCT_PHASES:
+        assert snap["histograms"][f"{name}_seconds"]["count"] >= 1

@@ -12,6 +12,7 @@ described inside a module fixture, never at import, and every case lives
 in this one file (one xdist worker loads the library for all of them).
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -22,7 +23,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.coded_matmul.kernel import coded_matmul_pallas
-from repro.kernels.coded_matmul.ops import worker_products_complex
+from repro.kernels.coded_matmul.ops import (worker_products,
+                                            worker_products_complex)
 from repro.kernels.poly_encode.kernel import poly_encode_pallas
 from repro.runtime.coded import distributed_coded_matmul
 
@@ -82,6 +84,30 @@ def test_worker_products_complex_compiles_for_v5e(one_chip):
     hlo = _hlo(lambda ar, ai, br, bi: worker_products_complex(
         ar, ai, br, bi, use_pallas=True), a, a, b, b)
     assert "tpu_custom_call" in hlo
+
+
+def _kernel_call_names(hlo: str) -> set[str]:
+    """Base names (``.N`` dropped) of the HLO's Pallas custom calls."""
+    calls = re.findall(r'%([\w.-]+) = [^\n]*custom_call_target='
+                       r'"tpu_custom_call"', hlo)
+    return {re.sub(r"\.\d+$", "", c) for c in calls}
+
+
+@pytest.mark.parametrize("complex_points", [False, True],
+                         ids=["worker_products", "worker_products_complex"])
+def test_kernel_keeps_the_name_the_trace_reduction_reads(one_chip,
+                                                         complex_points):
+    """The chip benchmark finds the kernel's device ops by this HLO name
+    (``coded_matmul_roofline.gflops``, ``KERNEL_OPS``); a rename must fail
+    here rather than silence the roofline metric."""
+    a, b = _spec((4 * N, ROWS, BZ), one_chip), _spec((4 * N, BZ, ROWS),
+                                                      one_chip)
+    if complex_points:
+        hlo = _hlo(lambda ar, ai, br, bi: worker_products_complex(
+            ar, ai, br, bi, use_pallas=True), a, a, b, b)
+    else:
+        hlo = _hlo(lambda x, y: worker_products(x, y, use_pallas=True), a, b)
+    assert _kernel_call_names(hlo) == {"coded_matmul_pallas"}
 
 
 @pytest.mark.parametrize("block", [(K, ROWS, BZ), (K, BZ, ROWS)],
