@@ -247,11 +247,19 @@ class CDCCode:
         entries are read); ``order``: completion order.
         """
         completed = np.asarray(order)[:m]
-        res = self.estimate_weights(completed, m)
+        # solve on the support in worker order, so the weights are those of
+        # the set of completions, as the serving decoders and their cache see
+        support = np.sort(completed[:self.decode_support(m)])
+        res = self.estimate_weights(
+            np.concatenate([support, completed[len(support):]]), m)
         if res is None:
             return None
         w, info = res
-        est = np.einsum("m,mij->ij", w, np.asarray(products)[completed[:len(w)]])
+        w_full = np.zeros(self.N, dtype=w.dtype)
+        w_full[support] = w
+        p = len(support)
+        est = np.einsum("m,mij->ij", w_full[completed[:p]],
+                        np.asarray(products)[completed[:p]])
         b = self.beta(info, m, beta_mode, oracle)
         est = b * est
         return np.real(est) if np.iscomplexobj(est) else est

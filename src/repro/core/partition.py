@@ -12,11 +12,13 @@ import numpy as np
 __all__ = ["split_contraction", "stack_blocks", "block_outer_products"]
 
 
-def split_contraction(A, B, K: int):
+def split_contraction(A, B, K: int, out=None):
     """Split ``A`` column-wise and ``B`` row-wise into ``K`` equal blocks.
 
     Returns ``(A_blocks, B_blocks)`` stacked on a leading axis:
-    ``A_blocks: (K, Nx, Nz//K)``, ``B_blocks: (K, Nz//K, Ny)``.
+    ``A_blocks: (K, Nx, Nz//K)``, ``B_blocks: (K, Nz//K, Ny)``.  For numpy
+    operands ``out``, a pair of arrays of those shapes, receives the blocks
+    (cast to their dtypes) in place of new arrays.
     """
     Nz = A.shape[1]
     if B.shape[0] != Nz:
@@ -24,11 +26,22 @@ def split_contraction(A, B, K: int):
     if Nz % K != 0:
         raise ValueError(f"contraction dim {Nz} not divisible by K={K}")
     step = Nz // K
-    A_blocks = np.stack([A[:, k * step:(k + 1) * step] for k in range(K)], axis=0) \
+    A_out, B_out = (None, None) if out is None else out
+    A_blocks = _fill(A.reshape(A.shape[0], K, step).swapaxes(0, 1), A_out) \
         if isinstance(A, np.ndarray) else _jnp_stack_cols(A, K, step)
-    B_blocks = np.stack([B[k * step:(k + 1) * step, :] for k in range(K)], axis=0) \
+    B_blocks = _fill(B.reshape(K, step, B.shape[1]), B_out) \
         if isinstance(B, np.ndarray) else _jnp_stack_rows(B, K, step)
     return A_blocks, B_blocks
+
+
+def _fill(blocks, out):
+    """``blocks`` copied into ``out``, or into a new array."""
+    if out is None:
+        out = np.empty(blocks.shape, blocks.dtype)
+    elif out.shape != blocks.shape:
+        raise ValueError(f"blocks {blocks.shape} do not fit {out.shape}")
+    np.copyto(out, blocks)
+    return out
 
 
 def _jnp_stack_cols(A, K, step):

@@ -145,27 +145,35 @@ class ExecutionBackend:
         """Per-worker completion times for one dispatched batch."""
         raise NotImplementedError
 
-    # shared host-side encode: one einsum over the stacked request blocks
+    # shared host-side encode: one GEMM per request and operand
     @staticmethod
     def _encode_batch(code: CDCCode, As, Bs, n_shards: int | None = None):
         """``(E_A: (B,n,Nx,bz), E_B: (B,n,bz,Ny))`` for the whole batch.
 
-        With ``n_shards`` the generator rows are sliced *before* the encode
-        einsums — a shrunk fleet saves the encode work too, not just the
-        worker occupancy.
+        Each operand of each request is one BLAS GEMM over its flattened
+        blocks, ``(n, K) @ (K, Nx·bz)``, in the generator's precision
+        (complex128 for complex points, float64 for real ones), written
+        straight into the batch's output.  With ``n_shards`` the generator
+        rows are sliced *before* the encode — a shrunk fleet saves the
+        encode work too, not just the worker occupancy.
         """
-        blocks = [split_contraction(np.asarray(A), np.asarray(B), code.K)
-                  for A, B in zip(As, Bs)]
-        A_blocks = np.stack([ab for ab, _ in blocks])    # (B, K, Nx, bz)
-        B_blocks = np.stack([bb for _, bb in blocks])    # (B, K, bz, Ny)
         G_A, G_B = code.generator()
         if n_shards is not None:
             if not 1 <= n_shards <= code.N:
                 raise ValueError(f"need 1 <= n_shards <= N={code.N}; got "
                                  f"{n_shards}")
             G_A, G_B = G_A[:n_shards], G_B[:n_shards]
-        E_A = np.einsum("nk,rkij->rnij", G_A, A_blocks)
-        E_B = np.einsum("nk,rkij->rnij", G_B, B_blocks)
+        As = [np.asarray(A) for A in As]
+        Bs = [np.asarray(B) for B in Bs]
+        (Nx, Nz), (_, Ny), K, n = As[0].shape, Bs[0].shape, code.K, len(G_A)
+        E_A = np.empty((len(As), n, Nx, Nz // K), np.result_type(G_A, *As))
+        E_B = np.empty((len(Bs), n, Nz // K, Ny), np.result_type(G_B, *Bs))
+        A_blocks = np.empty((K,) + E_A.shape[2:], E_A.dtype)
+        B_blocks = np.empty((K,) + E_B.shape[2:], E_B.dtype)
+        for r, (A, B) in enumerate(zip(As, Bs, strict=True)):
+            split_contraction(A, B, K, out=(A_blocks, B_blocks))
+            np.matmul(G_A, A_blocks.reshape(K, -1), out=E_A[r].reshape(n, -1))
+            np.matmul(G_B, B_blocks.reshape(K, -1), out=E_B[r].reshape(n, -1))
         return E_A, E_B
 
 

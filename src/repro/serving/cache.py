@@ -38,9 +38,9 @@ class DecodeWeightCache:
 
     One instance is shared service-wide (all requests, all codes — the code's
     ``cache_key()`` disambiguates).  A hit skips the Vandermonde solve
-    entirely; the weights are mathematically identical to a fresh solve and
-    numerically within solver noise (~ε·κ(V)) of it when the hitting
-    request's completion order differs from the one that populated the entry.
+    entirely; the decoders solve on the support in worker order, so a hit
+    equals a fresh solve bit for bit whatever the hitting request's
+    completion order.
 
     ``class_budget`` gives *every* request class its own sub-LRU of that
     size; ``class_budgets`` (a ``{RequestClass: size}`` map) assigns them

@@ -18,11 +18,13 @@ on the code's :meth:`~repro.core.codes.base.CDCCode.decode_update` hook:
   optionally skipped via the service-wide :class:`DecodeWeightCache` when the
   straggler pattern has been seen before.
 
-Equivalence contract: with a cold cache the resolve path calls
-``estimate_weights`` with the same completion-order prefix and recombines in
-the same order as :meth:`CDCCode.decode`, so its estimates are bit-identical
-to a from-scratch decode; the rank-1 path differs only by float64 summation
-order (≲1e-14 relative).  ``tests/test_serving.py`` pins both.
+Equivalence contract: the resolve path solves on the decode's support in
+worker order, so its weights are a function of the set of completions
+alone and a cache hit equals a fresh solve bit for bit; it solves and
+recombines as :meth:`CDCCode.decode` does, so its estimates are
+bit-identical to a from-scratch decode; the rank-1 path differs only by
+float64 summation order (≲1e-14 relative).  ``tests/test_serving.py`` pins
+both.
 
 :class:`RecomputeDecoder` is the per-tick-re-decode baseline behind the
 ``decoder="recompute"`` serving mode and the throughput benchmark.
@@ -62,6 +64,7 @@ class IncrementalDecoder:
         self._order = np.empty(code.N, dtype=np.int64)
         self._buf = None                 # (N, Nx, Ny) products, completion order
         self._m = 0
+        self.product_eps = None          # rounding unit of the ingested dtype
         # rank-1 (cluster-mean) state
         cs = code.cluster_structure()
         self._cluster = self._alphas = self._csums = self._U = None
@@ -83,6 +86,31 @@ class IncrementalDecoder:
         """Completions ingested so far."""
         return self._m
 
+    @property
+    def completed(self) -> np.ndarray:
+        """Workers ingested so far, in completion order."""
+        return self._order[:self._m].copy()
+
+    def products(self) -> np.ndarray:
+        """The ingested products, ``(m, Nx, Ny)`` in completion order."""
+        return self._buf[:self._m]
+
+    def refine(self, products: np.ndarray) -> None:
+        """Replace the ingested products by ``products`` (completion order),
+        the same products at a higher precision; later estimates read them.
+        """
+        products = np.asarray(products)
+        self._buf[:self._m] = products
+        self.product_eps = np.finfo(products.dtype).eps
+        self._resolved = None
+        if self._cluster is not None:
+            self._counts[:] = 0
+            self._csums[:] = 0
+            self._U[:] = 0
+            for i in range(self._m):
+                if self.code.decode_update(i + 1) == "rank1":
+                    self._rank1_update(int(self._order[i]), self._buf[i])
+
     def push(self, worker: int, product: np.ndarray) -> None:
         """Ingest worker ``worker``'s product as the next completion.
 
@@ -101,6 +129,7 @@ class IncrementalDecoder:
         if self._buf is None:
             dt = np.result_type(product.dtype, np.float64)
             self._buf = np.empty((self.code.N,) + product.shape, dtype=dt)
+            self.product_eps = np.finfo(product.dtype).eps
             if self._cluster is not None:
                 self._csums = np.zeros((self.code.K,) + product.shape, dt)
                 self._U = np.zeros(product.shape, dt)
@@ -163,28 +192,31 @@ class IncrementalDecoder:
         code = self.code
         completed = self._order[:m]
         p = code.decode_support(m)
-        key = None
+        key = hit = None
         if self.cache is not None:
             key = DecodeWeightCache.key(code, completed[:p], p,
                                         self.beta_mode)
             hit = self.cache.get(key)
-            if hit is not None:
-                w_full, info = hit
-                self.stats["cache_hit"] += 1
-                # recombine in this request's completion order
-                w = w_full[completed[:p]]
-                pre = np.einsum("m,mij->ij", w, self._buf[:p])
-                return pre, info, w_full
-        res = code.estimate_weights(completed, m)
-        if res is None:                              # defensive; guarded above
-            raise ValueError(f"no estimate at m={m} for {code.name}")
-        w, info = res
-        self.stats["resolve"] += 1
-        pre = np.einsum("m,mij->ij", w, self._buf[:len(w)])
-        w_full = np.zeros(code.N, dtype=np.result_type(w.dtype, np.float64))
-        w_full[completed[:len(w)]] = w
-        if key is not None:
-            self.cache.put(key, (w_full, info))
+        if hit is not None:
+            w_full, info = hit
+            self.stats["cache_hit"] += 1
+        else:
+            # solve on the support in worker order: the weights then depend
+            # on the set of completions only, as the cache key does
+            support = np.sort(completed[:p])
+            res = code.estimate_weights(
+                np.concatenate([support, completed[p:]]), m)
+            if res is None:                          # defensive; guarded above
+                raise ValueError(f"no estimate at m={m} for {code.name}")
+            w, info = res
+            self.stats["resolve"] += 1
+            w_full = np.zeros(code.N,
+                              dtype=np.result_type(w.dtype, np.float64))
+            w_full[support] = w
+            if key is not None:
+                self.cache.put(key, (w_full, info))
+        # recombine in this request's completion order
+        pre = np.einsum("m,mij->ij", w_full[completed[:p]], self._buf[:p])
         return pre, info, w_full
 
     def weight_vector(self) -> np.ndarray | None:
@@ -230,12 +262,25 @@ class RecomputeDecoder:
         self._order = np.empty(code.N, dtype=np.int64)
         self._by_worker = None           # (N, Nx, Ny) products by worker id
         self._m = 0
+        self.product_eps = None
         self._seen: set[int] = set()
         self.stats = {"push": 0, "decode": 0, "dup_ignored": 0}
 
     @property
     def m(self) -> int:
         return self._m
+
+    @property
+    def completed(self) -> np.ndarray:
+        return self._order[:self._m].copy()
+
+    def products(self) -> np.ndarray:
+        return self._by_worker[self._order[:self._m]]
+
+    def refine(self, products: np.ndarray) -> None:
+        products = np.asarray(products)
+        self._by_worker[self._order[:self._m]] = products
+        self.product_eps = np.finfo(products.dtype).eps
 
     def push(self, worker: int, product: np.ndarray) -> None:
         if int(worker) in self._seen:     # duplicate completion: idempotent
@@ -248,6 +293,7 @@ class RecomputeDecoder:
         if self._by_worker is None:
             dt = np.result_type(product.dtype, np.float64)
             self._by_worker = np.zeros((self.code.N,) + product.shape, dt)
+            self.product_eps = np.finfo(product.dtype).eps
         self._order[self._m] = worker
         self._by_worker[worker] = product
         self._m += 1

@@ -626,21 +626,55 @@ class MasterScheduler:
 
     def _open_track(self, batch, decoders, refs, results, m: int, R: int,
                     t_glob: float) -> None:
-        """Stamp ``t_target`` for requests whose accuracy SLO was just met."""
+        """Stamp ``t_target`` for requests whose accuracy SLO was just met.
+
+        A stamped request is released: its decoder ingests nothing more, so
+        its estimate stays the one that met the target.  At the exact layer
+        every target is met; an estimate there that misses its target is
+        first decoded again from float64 products (:meth:`_refine_exact`).
+        """
         with self.metrics.span("serve.oracle"):
             for r, dec, (C, norm, _), res in zip(batch, decoders, refs,
                                                  results):
                 if r.target is None or res.t_target is not None:
                     continue
-                if m >= R:                 # exact: every target is met
-                    res.t_target = t_glob
+                if C is None or norm <= 0.0:
+                    if m >= R:
+                        res.t_target = t_glob
                     continue
                 est = dec.estimate()
-                if est is None or C is None or norm <= 0.0:
+                if est is None:
                     continue
                 err = float(np.linalg.norm(est - C) ** 2 / norm)
-                if err <= r.target:
+                if m >= R and err > r.target:
+                    self._refine_exact(dec, r.A, r.B)
+                if m >= R or err <= r.target:
                     res.t_target = t_glob
+
+    @staticmethod
+    def _refine_exact(dec, A, B) -> bool:
+        """Give ``dec`` float64 host products in place of its own, if those
+        are of a lower precision and differ from them only by rounding.
+
+        From float32 products the exact decode on a clustered completion
+        order amplifies their rounding past any target; wrong products
+        (beyond the rounding bound of a ``bz``-term dot product) are left
+        as they are.  Returns whether the products were replaced.
+        """
+        eps = dec.product_eps
+        if eps is None or eps <= np.finfo(np.float64).eps:
+            return False
+        workers = dec.completed
+        E_A, E_B = ExecutionBackend._encode_batch(dec.code, [A], [B])
+        E_A, E_B = E_A[0, workers], E_B[0, workers]
+        exact = np.matmul(E_A, E_B)
+        bound = (E_A.shape[-1] + 2) * eps * (
+            np.linalg.norm(E_A, axis=(1, 2)) * np.linalg.norm(E_B, axis=(1, 2)))
+        if np.any(np.linalg.norm(dec.products() - exact, axis=(1, 2))
+                  > bound):
+            return False
+        dec.refine(exact)
+        return True
 
     @staticmethod
     def _open_settled(batch, results, m: int, R: int) -> bool:
@@ -797,7 +831,8 @@ class MasterScheduler:
                         speculative=spec)
                     with self.metrics.span("serve.decode_push") as sp:
                         for i, dec in enumerate(decoders):
-                            dec.push(ev.shard, ev.products[i])
+                            if results[i].t_target is None:   # not released
+                                dec.push(ev.shard, ev.products[i])
                     self.tracer.decode_apply(bid, ev.shard, ev.t,
                                              dur=sp.seconds)
                     shard_times[ev.shard] = ev.t
@@ -962,8 +997,8 @@ class MasterScheduler:
                 if est is not None and C is not None and norm > 0.0:
                     err = float(np.linalg.norm(est - C) ** 2 / norm)
                     errs.append(err)
-                res.answers.append(Answer(t=t, m=m, rel_err=err,
-                                          exact=m >= R, kind=kind))
+                res.answers.append(Answer(t=t, m=dec.m, rel_err=err,
+                                          exact=dec.m >= R, kind=kind))
         if errs:               # the sampler's anytime-accuracy trajectory
             self._g_err.set(sum(errs) / len(errs))
         if kind == "deadline":

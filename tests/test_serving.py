@@ -421,6 +421,229 @@ def test_decoder_push_is_idempotent_per_worker():
         assert np.linalg.norm(est - A @ B) / np.linalg.norm(A @ B) < 1e-10
 
 
+# ------------------------------------------- order-free solve and refinement
+
+@pytest.mark.parametrize("name", sorted(serving_code_matrix()))
+def test_cache_hit_equals_fresh_solve_bitwise(name):
+    """A warm entry made by another completion order of the same set gives
+    the estimate a cold decoder computes, bit for bit: the solve runs on
+    the support in worker order."""
+    code = serving_code_matrix()[name]
+    rng = np.random.default_rng(12)
+    P = code.run_workers(rng.standard_normal((6, 48)),
+                         rng.standard_normal((48, 6)))
+    first = rng.permutation(code.N)
+    for m in range(code.first_threshold, code.N + 1):
+        cache = DecodeWeightCache()
+        warm = IncrementalDecoder(code, cache=cache)
+        for w in first[:m]:
+            warm.push(int(w), P[w])
+        warm.estimate()
+        again = np.concatenate([rng.permutation(first[:m]), first[m:]])
+        warm, cold = (IncrementalDecoder(code, cache=cache),
+                      IncrementalDecoder(code))
+        for w in again[:m]:
+            warm.push(int(w), P[w])
+            cold.push(int(w), P[w])
+        np.testing.assert_array_equal(warm.estimate(), cold.estimate())
+
+
+@pytest.mark.parametrize("kind", ["incremental", "recompute"])
+@pytest.mark.parametrize("name", sorted(serving_code_matrix()))
+def test_refine_equals_ingesting_the_refined_products(name, kind):
+    """``refine`` leaves a decoder as if it had ingested the new products
+    from the start, below and at the recovery threshold."""
+    code = serving_code_matrix()[name]
+    rng = np.random.default_rng(13)
+    P = code.run_workers(rng.standard_normal((6, 48)),
+                         rng.standard_normal((48, 6)))
+    low = P.astype(np.complex64 if np.iscomplexobj(P) else np.float32)
+    order = rng.permutation(code.N)
+    for m in (code.first_threshold + 1, code.recovery_threshold):
+        dec, want = make_decoder(kind, code), make_decoder(kind, code)
+        for w in order[:m]:
+            dec.push(int(w), low[w])
+            want.push(int(w), P[w])
+        assert dec.product_eps == np.finfo(np.float32).eps
+        dec.refine(P[dec.completed])
+        assert dec.product_eps == np.finfo(np.float64).eps
+        np.testing.assert_array_equal(dec.products(), want.products())
+        np.testing.assert_array_equal(dec.estimate(), want.estimate())
+
+
+# a completion order that starts on adjacent points of the radius-0.1
+# circle: from float32 products, one of the four requests of the batch
+# below misses 0.4 at every layer below R, the other three meet it at m = 6
+CLUSTERED = [19, 22, 21, 20, 18, 16, 1, 15, 13, 0, 2, 5, 10, 3, 17, 7, 23, 9,
+             11, 8, 4, 6, 14, 12]
+
+
+@pytest.mark.parametrize("products", ["rounded", "wrong", "float64"])
+def test_refine_exact_replaces_rounding_only(products):
+    """At the exact layer, float32 products are replaced by float64 host
+    products when they differ only by rounding; wrong products and
+    float64 products are left as they are."""
+    from repro.serving import MasterScheduler
+    code = GroupSACCode(K, N, x_complex(N, 0.1), [5, 3])
+    rng = np.random.default_rng(3)
+    A, B = rng.standard_normal((100, 800)), rng.standard_normal((800, 100))
+    P = code.run_workers(A, B)
+    if products != "float64":
+        P = P.astype(np.complex64)
+    if products == "wrong":
+        P[:, 0, 0] += 10 * np.abs(P).max()
+    dec = IncrementalDecoder(code)
+    for w in CLUSTERED[:code.recovery_threshold]:
+        dec.push(w, P[w])
+    C = A @ B
+
+    def err():
+        return np.linalg.norm(dec.estimate() - C) ** 2 / np.linalg.norm(C) ** 2
+
+    before = err()
+    assert MasterScheduler._refine_exact(dec, A, B) == (products == "rounded")
+    if products == "rounded":
+        assert before > 1.0 and err() < 1e-3
+    else:
+        assert err() == before
+
+
+def test_open_release_holds_each_request_at_its_layer():
+    """Open loop with an accuracy target on float32 products: a request
+    is released with the estimate that met its target, and one that meets
+    it at no layer below R is decoded at R from float64 host products."""
+    from repro.serving import MasterScheduler
+    from repro.serving.loadgen import OpenRequest, TenantSpec
+
+    class Rounded(SimulatedBackend):
+        def compute_products(self, code, As, Bs, n_shards=None):
+            E_A, E_B = self._encode_batch(code, As, Bs, n_shards)
+            return np.matmul(E_A.astype(np.complex64),
+                             E_B.astype(np.complex64))
+
+        def draw_latencies(self, rng, N):
+            t = np.empty(N)
+            t[CLUSTERED] = 1.0 + np.arange(N) / N
+            return t
+
+    class Capturing(MasterScheduler):
+        def _prepare_batch(self, batch, code, cfg):
+            out = super()._prepare_batch(batch, code, cfg)
+            self.decoders = out[1]
+            return out
+
+    code = GroupSACCode(K, N, x_complex(N, 0.1), [5, 3])
+    sched = Capturing(code, Rounded(), ServeConfig(batch_size=4, seed=0))
+    ten = TenantSpec("t", target_error=0.4, deadline=None)
+    rng = np.random.default_rng(0)
+    pairs = [(rng.standard_normal((100, 800)),
+              rng.standard_normal((800, 100))) for _ in range(4)]
+    results = sched.run_open([OpenRequest(0.0, A, B, tenant=ten)
+                              for A, B in pairs])
+    R, ms = code.recovery_threshold, []
+    for (A, B), dec, res in zip(pairs, sched.decoders, results):
+        C = A @ B
+        assert np.linalg.norm(dec.estimate() - C) ** 2 \
+            / np.linalg.norm(C) ** 2 <= 0.4
+        assert res.slo_ok
+        ms.append(dec.m)
+        if dec.m == R:
+            assert dec.product_eps == np.finfo(np.float64).eps
+    assert R in ms and min(ms) < R, ms
+
+
+# ------------------------------------------------------------ batch encode
+
+ENCODE_CODES = {
+    "gsac_complex": lambda: GroupSACCode(K, N, x_complex(N, 0.1), [5, 3]),
+    "lsac_real": lambda: LayerSACCode(K, N, base="ortho", eps=6.25e-3),
+}
+
+
+@pytest.mark.parametrize("n_shards", [None, N - 5])
+@pytest.mark.parametrize("batch", [1, 4])
+@pytest.mark.parametrize("family", sorted(ENCODE_CODES))
+def test_encode_batch_matches_generator(family, batch, n_shards):
+    """The batch encode is ``Σ_k G[n, k]·blocks[k]`` per request, in the
+    generator's precision, C-ordered, with ``n_shards`` rows kept."""
+    from repro.serving.backends import ExecutionBackend
+    code = ENCODE_CODES[family]()
+    rng = np.random.default_rng(batch)
+    As = [rng.standard_normal((12, 40)) for _ in range(batch)]
+    Bs = [rng.standard_normal((40, 6)) for _ in range(batch)]
+    E_A, E_B = ExecutionBackend._encode_batch(code, As, Bs, n_shards)
+    G_A, G_B = code.generator()
+    n = code.N if n_shards is None else n_shards
+    dtype = np.complex128 if family == "gsac_complex" else np.float64
+    splits = [split_contraction(A, B, K) for A, B in zip(As, Bs)]
+    for side, (E, G) in enumerate(((E_A, G_A), (E_B, G_B))):
+        assert E.dtype == G.dtype == dtype
+        assert E.flags.c_contiguous
+        assert E.shape == (batch, n) + splits[0][side].shape[1:]
+        for r in range(batch):
+            blocks = splits[r][side]
+            want = np.zeros((n,) + blocks.shape[1:], dtype)
+            for i in range(n):
+                for k in range(K):
+                    want[i] += G[i, k] * blocks[k]
+            rel = np.abs(E[r] - want).max() / np.abs(want).max()
+            assert rel < 1e-13, (family, side, r, rel)
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.complex128])
+def test_split_contraction_fills_buffers_as_new_blocks(dtype):
+    """``out`` receives the same blocks a fresh split returns, cast to the
+    buffers' dtype, and the buffers are what comes back."""
+    rng = np.random.default_rng(8)
+    A, B = rng.standard_normal((6, 20)), rng.standard_normal((20, 3))
+    want = split_contraction(A, B, 4)
+    out = (np.empty((4, 6, 5), dtype), np.empty((4, 5, 3), dtype))
+    got = split_contraction(A, B, 4, out=out)
+    for g, o, w in zip(got, out, want):
+        assert g is o and g.dtype == dtype
+        np.testing.assert_array_equal(g, w)
+    for k in range(4):
+        np.testing.assert_array_equal(want[0][k], A[:, 5 * k:5 * (k + 1)])
+        np.testing.assert_array_equal(want[1][k], B[5 * k:5 * (k + 1)])
+
+
+def test_encode_batch_refuses_mixed_shapes():
+    """One batch is one shape: a request that differs fails loudly instead
+    of broadcasting into the batch's block buffers."""
+    from repro.serving.backends import ExecutionBackend
+    code = MatDotCode(4, 8, chebyshev_roots(8))
+    rng = np.random.default_rng(6)
+    As = [rng.standard_normal((8, 16)), rng.standard_normal((1, 16))]
+    Bs = [rng.standard_normal((16, 4)), rng.standard_normal((16, 4))]
+    with pytest.raises(ValueError, match="do not fit"):
+        ExecutionBackend._encode_batch(code, As, Bs)
+    with pytest.raises(ValueError, match="divisible by K"):
+        ExecutionBackend._encode_batch(code, [As[0][:, :10]], [Bs[0][:10]])
+
+
+def test_device_backend_encodes_once_per_batch_through_the_hook():
+    """``compute_products`` encodes through ``self._encode_batch``, once a
+    batch, so a subclass that wraps the hook (a timer) sees every encode."""
+    from repro.serving import DeviceBackend
+
+    class Counting(DeviceBackend):
+        calls = 0
+
+        def _encode_batch(self, code, As, Bs, n_shards=None):
+            self.calls += 1
+            return DeviceBackend._encode_batch(code, As, Bs, n_shards)
+
+    code = GroupSACCode(K, N, x_complex(N, 0.1), [5, 3])
+    rng = np.random.default_rng(5)
+    backend = Counting(use_pallas=False)
+    for batch in (1, 3):
+        As = [rng.standard_normal((8, 16)) for _ in range(batch)]
+        Bs = [rng.standard_normal((16, 4)) for _ in range(batch)]
+        P = backend.compute_products(code, As, Bs)
+        assert P.shape == (batch, N, 8, 4)
+    assert backend.calls == 2
+
+
 # ------------------------------------------------------------- device backend
 
 def test_device_backend_matches_simulated_real():
