@@ -37,7 +37,12 @@ from ..core.codes.base import CDCCode, DecodeInfo
 from ..names import unknown_name
 from .cache import DecodeWeightCache
 
-__all__ = ["IncrementalDecoder", "RecomputeDecoder", "make_decoder"]
+__all__ = ["IncrementalDecoder", "RecomputeDecoder", "make_decoder",
+           "DECODE_STATS"]
+
+# the update counts both decoders keep in ``stats`` (besides
+# ``dup_ignored``); the scheduler adds them to ``serve.decode.<key>``
+DECODE_STATS = ("push", "rank1", "resolve", "cache_hit", "reuse")
 
 
 class IncrementalDecoder:
@@ -77,8 +82,7 @@ class IncrementalDecoder:
         # resolve-regime state: (pre-β estimate, info, scattered weights)
         self._resolved = None
         self._seen: set[int] = set()
-        self.stats = {"push": 0, "rank1": 0, "resolve": 0, "reuse": 0,
-                      "cache_hit": 0, "dup_ignored": 0}
+        self.stats = dict.fromkeys(DECODE_STATS + ("dup_ignored",), 0)
 
     # ------------------------------------------------------------- ingestion
     @property
@@ -250,7 +254,8 @@ class RecomputeDecoder:
 
     This is exactly what the pre-streaming ``launch/serve.py`` did at each
     deadline — kept as the A/B arm for ``benchmarks/serve_throughput.py`` and
-    the equivalence tests.
+    the equivalence tests.  Its ``stats`` keep the incremental decoder's
+    keys; every estimate is a from-scratch decode, counted as a ``resolve``.
     """
 
     def __init__(self, code: CDCCode, *, beta_mode: str = "one",
@@ -264,7 +269,7 @@ class RecomputeDecoder:
         self._m = 0
         self.product_eps = None
         self._seen: set[int] = set()
-        self.stats = {"push": 0, "decode": 0, "dup_ignored": 0}
+        self.stats = dict.fromkeys(DECODE_STATS + ("dup_ignored",), 0)
 
     @property
     def m(self) -> int:
@@ -302,7 +307,7 @@ class RecomputeDecoder:
     def estimate(self) -> np.ndarray | None:
         if self._m < self.code.first_threshold:
             return None
-        self.stats["decode"] += 1
+        self.stats["resolve"] += 1
         return self.code.decode(self._by_worker, self._order[:self._m],
                                 self._m, self.beta_mode, self.oracle)
 

@@ -58,13 +58,15 @@ from ..obs import NULL_BURN, NULL_FLIGHT, NULL_REGISTRY, NULL_SAMPLER, \
 from .backends import ExecutionBackend, SimulatedBackend
 from ..names import unknown_name
 from .cache import DecodeWeightCache
-from .incremental import make_decoder
+from .incremental import DECODE_STATS, make_decoder
 
 __all__ = ["ServeConfig", "MatmulRequest", "Answer", "RequestResult",
            "MasterScheduler", "serve_request", "merged_event_stream",
            "QUEUE_POLICIES"]
 
 QUEUE_POLICIES = ("fifo", "edf")
+# ``serve.release_m``: one bucket per completion count up to 32 workers
+RELEASE_M_BUCKETS = tuple(range(1, 33))
 
 
 def merged_event_stream(t_sorted, deadlines) -> list[tuple[float, int, int]]:
@@ -204,6 +206,11 @@ class MasterScheduler:
         self._h_ttfa = self.metrics.histogram("serve.tta_first_seconds")
         self._h_tta = self.metrics.histogram("serve.tta_exact_seconds")
         self._c_shed = self.metrics.counter("serve.shed")
+        # per batch: the decoders' update counts and each request's m
+        self._c_decode = {k: self.metrics.counter(f"serve.decode.{k}")
+                          for k in DECODE_STATS}
+        self._h_release_m = self.metrics.histogram("serve.release_m",
+                                                   RELEASE_M_BUCKETS)
         # global serve clock for closed-loop telemetry: accumulated batch
         # spans, so sampler ticks share one timeline with open-loop runs
         self._clock = 0.0
@@ -933,6 +940,13 @@ class MasterScheduler:
                 self._hedge_rows.append(row)
         for res, dec in zip(results, decoders):
             res.decode_stats = dict(dec.stats)
+        if self._m_on:
+            # a released decoder ingested nothing after its release, so its
+            # m is the completions in the estimate it was released with
+            for dec in decoders:
+                self._h_release_m.observe(dec.m)
+                for k, c in self._c_decode.items():
+                    c.inc(dec.stats[k])
         return results
 
     # ------------------------------------------------------------ speculation

@@ -5,7 +5,9 @@ profiler annotation of the same name; a disabled registry hands out one
 shared no-op span.  The device backend splits its products into encode,
 cast, copy in, device wait and copy back, and counts the bytes copied each
 way; the scheduler wraps each batch, the accuracy-SLO oracle and the decode
-pushes and ticks.  None of it may change a served number.
+pushes and ticks, and adds the decoders' update counts and each request's
+release layer to ``serve.decode.*`` and ``serve.release_m`` once a batch.
+None of it may change a served number.
 """
 import glob
 import time
@@ -16,8 +18,11 @@ import pytest
 from repro.core import GroupSACCode, x_complex
 from repro.core.registry import make_code
 from repro.obs import NULL_REGISTRY, MetricsRegistry
-from repro.serving import (DeviceBackend, MasterScheduler, ServeConfig,
+from repro.serving import (DeviceBackend, IncrementalDecoder,
+                           MasterScheduler, RecomputeDecoder, ServeConfig,
                            SimulatedBackend)
+from repro.serving.incremental import DECODE_STATS
+from repro.serving.loadgen import OpenRequest, TenantSpec
 
 ROWS, INNER, COLS = 8, 16, 6
 # direct children of serve.batch: what is left is the scheduler's own work
@@ -245,3 +250,82 @@ def test_serve_cli_metrics_out_carries_device_phases(tmp_path):
     assert snap["counters"]["serve.d2h_bytes"] > 0
     for name in ("serve.batch", "serve.products") + PRODUCT_PHASES:
         assert snap["histograms"][f"{name}_seconds"]["count"] >= 1
+
+
+# ------------------------------------------------ the decoders' counters
+
+def _serve_open(kind, reg, decoder="incremental", n_req=4):
+    """One batch of ``n_req`` requests with accuracy target 0.4."""
+    sched = MasterScheduler(_codes()[kind], SimulatedBackend(),
+                            ServeConfig(batch_size=n_req, seed=3,
+                                        decoder=decoder), metrics=reg)
+    tenant = TenantSpec("t", rows=ROWS, inner=INNER, target_error=0.4,
+                        deadline=None)
+    As, Bs = _operands(n_req, seed=5)
+    return sched.run_open([OpenRequest(0.0, A, B, tenant)
+                           for A, B in zip(As, Bs)])
+
+
+def _counters(reg):
+    c = reg.snapshot()["counters"]
+    return {k: c[f"serve.decode.{k}"] for k in DECODE_STATS}
+
+
+def test_lsac_batch_counts_rank1_pushes_and_no_resolve():
+    reg = MetricsRegistry()
+    results = _serve_open("real", reg)
+    R = _codes()["real"].recovery_threshold
+    pushes = [r.decode_stats["push"] for r in results]
+    assert max(pushes) < R                   # released at cluster layers
+    got = _counters(reg)
+    assert got["push"] == sum(pushes)
+    assert got["rank1"] == sum(min(p, R - 1) for p in pushes) == sum(pushes)
+    assert got["resolve"] == got["cache_hit"] == 0
+
+
+def test_gsac_batch_counts_no_rank1():
+    reg = MetricsRegistry()
+    results = _serve_open("complex", reg)
+    got = _counters(reg)
+    assert got["push"] == sum(r.decode_stats["push"] for r in results) > 0
+    assert got["rank1"] == 0
+    assert got["resolve"] + got["cache_hit"] > 0
+
+
+@pytest.mark.parametrize("kind", ["real", "complex"])
+def test_release_m_observed_once_per_request(kind):
+    reg = MetricsRegistry()
+    results = _serve_open(kind, reg)
+    h = reg.snapshot()["histograms"]["serve.release_m"]
+    assert h["count"] == len(results) == 4
+    # a released decoder ingests nothing more: its pushes are its m
+    assert h["total"] == sum(r.decode_stats["push"] for r in results)
+
+
+def test_release_m_is_the_last_m_without_a_target():
+    reg = MetricsRegistry()
+    results = _serve(SimulatedBackend(), reg, n_req=3)
+    h = reg.snapshot()["histograms"]["serve.release_m"]
+    assert h["count"] == 3
+    assert h["min"] == h["max"] == _codes()["real"].N
+
+
+def test_both_decoders_report_the_same_counter_keys():
+    code = _codes()["real"]
+    assert set(IncrementalDecoder(code).stats) \
+        == set(RecomputeDecoder(code).stats) >= set(DECODE_STATS)
+    reg = MetricsRegistry()
+    _serve_open("real", reg, decoder="recompute")
+    got = _counters(reg)
+    assert got["push"] > 0 and got["resolve"] > 0 and got["rank1"] == 0
+
+
+def test_decode_counters_are_null_without_a_registry():
+    sched = MasterScheduler(_codes()["real"], SimulatedBackend())
+    assert all(c is NULL_REGISTRY.counter("serve.decode.push")
+               for c in sched._c_decode.values())
+    assert sched._h_release_m is NULL_REGISTRY.histogram("serve.release_m")
+    assert set(sched._c_decode) == set(DECODE_STATS)
+    _serve_open("real", None)
+    assert NULL_REGISTRY.snapshot() == {"counters": {}, "gauges": {},
+                                        "histograms": {}}
