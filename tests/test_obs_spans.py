@@ -136,6 +136,104 @@ def test_device_products_bit_identical_with_spans_on_and_off(kind, dtype):
     np.testing.assert_array_equal(got_on, before)
 
 
+# a chunk small enough that the test's planes are cast in several chunks
+SMALL_CHUNK = 512
+
+
+@pytest.mark.parametrize("chunk", [None, SMALL_CHUNK])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["real", "complex"])
+def test_device_products_bit_identical_through_reused_staging(
+        kind, dtype, chunk, monkeypatch):
+    """One backend, batches that grow the staging buffers and batches that
+    take prefix views of them: every batch's products equal the unstaged
+    cast's, and none changes when a later batch reuses the buffers."""
+    import jax.numpy as jnp
+
+    from repro.serving import backends
+    if chunk is not None:
+        monkeypatch.setattr(backends, "_CHUNK_BYTES", chunk)
+    code = _codes()[kind]
+    backend = DeviceBackend(use_pallas=False, dtype=getattr(jnp, dtype))
+    kept = []
+    for rows, inner, cols in ((ROWS, INNER, COLS), (ROWS + 4, 2 * INNER, 5)):
+        for n in (3, 1, 4):
+            rng = np.random.default_rng(10 * n + rows)
+            As = [rng.standard_normal((rows, inner)) for _ in range(n)]
+            Bs = [rng.standard_normal((inner, cols)) for _ in range(n)]
+            got = backend.compute_products(code, As, Bs)
+            np.testing.assert_array_equal(
+                got, _products_before_spans(backend, code, As, Bs))
+            kept.append((got, got.copy()))
+            for earlier, copy in kept:
+                np.testing.assert_array_equal(earlier, copy)
+
+
+@pytest.mark.parametrize("kind", ["real", "complex"])
+def test_staging_counters_grow_once_then_count_reuse(kind):
+    code = _codes()[kind]
+    reg = MetricsRegistry()
+    backend = DeviceBackend(use_pallas=False, metrics=reg)
+    backend.compute_products(code, *_operands(4))
+    parts = 2 if kind == "complex" else 1        # real and imaginary
+    W, bz, f32 = 4 * code.N, INNER // code.K, 4
+    planes_bytes = parts * W * (ROWS * bz + bz * COLS) * f32
+    counters = reg.snapshot()["counters"]
+    assert counters["serve.cast.stage_bytes"] == planes_bytes
+    assert counters["serve.cast.staged"] == 0
+    for n in (4, 2, 1):
+        backend.compute_products(code, *_operands(n, seed=n))
+    counters = reg.snapshot()["counters"]
+    assert counters["serve.cast.stage_bytes"] == planes_bytes
+    assert counters["serve.cast.staged"] == 3
+
+
+def test_staging_counters_are_null_without_a_registry():
+    backend = DeviceBackend(use_pallas=False)
+    for n in (2, 1):
+        backend.compute_products(_codes()["complex"], *_operands(n))
+    assert backend._c_staged is NULL_REGISTRY.counter("serve.cast.staged")
+    assert NULL_REGISTRY.snapshot()["counters"] == {}
+
+
+def test_backend_shared_across_threads_keeps_each_batch(monkeypatch):
+    """More threads than cores call one backend at once, with a short
+    switch interval; each must get its own batch's products, as a backend
+    of its own computes them."""
+    import os
+    import sys
+    import threading
+
+    from repro.serving import backends
+    monkeypatch.setattr(backends, "_CHUNK_BYTES", SMALL_CHUNK)
+    code = _codes()["complex"]
+    n_threads = 2 * len(os.sched_getaffinity(0)) + 1
+    jobs = [_operands(1 + i % 4, seed=100 + i) for i in range(n_threads)]
+    want = [DeviceBackend(use_pallas=False).compute_products(code, *j)
+            for j in jobs]
+    shared = DeviceBackend(use_pallas=False)
+    got = [None] * n_threads
+
+    def work(i):
+        for _ in range(3):
+            got[i] = shared.compute_products(code, *jobs[i])
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    for g, w in zip(got, want, strict=True):
+        np.testing.assert_array_equal(g, w)
+
+
 @pytest.mark.parametrize("kind", ["real", "complex"])
 def test_device_byte_counters_follow_the_shapes(kind):
     code = _codes()[kind]
