@@ -187,8 +187,7 @@ def mesh_phase(seed: int, clock: CompileClock) -> list[dict]:
     from repro.launch.mesh import make_mesh
     from repro.launch.serve import build_code
     from repro.runtime.coded import (decode_weight_vector,
-                                     distributed_coded_matmul,
-                                     encode_operands)
+                                     distributed_coded_matmul)
     from repro.serving import DeviceBackend
     devices = jax.devices()
     check(len(devices) == 4, f"the mesh phase needs 4 chips; jax sees "
@@ -198,7 +197,7 @@ def mesh_phase(seed: int, clock: CompileClock) -> list[dict]:
     rng = np.random.default_rng(seed)
     A = rng.standard_normal((ROWS, INNER))
     B = rng.standard_normal((INNER, ROWS))
-    E_A, E_B = encode_operands(code, *split_contraction(A, B, K))
+    E_A, E_B = code.encode(*split_contraction(A, B, K))
     P = np.einsum("nij,njl->nil", E_A, E_B)          # host f64 products
     order = rng.permutation(N)
 

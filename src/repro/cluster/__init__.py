@@ -8,10 +8,8 @@ executes encode shards on real OS processes and feeds the serving loop
 * :mod:`~repro.cluster.config`  — :class:`ClusterConfig` /
   :data:`global_config`: the runtime's tunables in the alpa
   ``GlobalConfig`` idiom (env-var defaults, explicit kwargs win).
-* :mod:`~repro.cluster.worker`  — worker processes (injectable chaos:
-  sleep jitter / slow hosts / crash / hang) and the **compute seam**:
-  :class:`ShardComputer` with numpy and device (Pallas kernel-op)
-  implementations.
+* :mod:`~repro.cluster.worker`  — worker processes (host shard products,
+  injectable chaos: sleep jitter / slow hosts / crash / hang).
 * :mod:`~repro.cluster.transport` — the **transport seam**:
   :class:`Transport` (framed messages, operand broadcast, result
   streaming, heartbeat) with ``local`` pipes/shm and ``socket`` TCP.
@@ -24,24 +22,22 @@ executes encode shards on real OS processes and feeds the serving loop
 * :mod:`~repro.cluster.backend` — :class:`ClusterBackend` (live dispatch
   for the serving loop) and :class:`ReplayBackend`.
 
-``worker`` is the multiprocessing spawn target, so this module stays
-importable without jax; the backend (which pulls in the serving package) is
-loaded lazily.
+The cluster is host-only: it is the fixture for speculation, chaos, the
+socket transport and record/replay.  Worker products on the chip go through
+:class:`~repro.serving.backends.DeviceBackend`.  ``worker`` is the
+multiprocessing spawn target, so this module stays importable without jax;
+the backend (which pulls in the serving package) is loaded lazily.
 """
 from .config import ClusterConfig, global_config
 from .events import BatchRecord, ShardEvent, TraceRecording
 from .pool import WorkerHandle, WorkerPool
 from .transport import (LocalTransport, SocketTransport, Transport,
                         TransportClosed, make_transport)
-from .worker import (ChaosSpec, ComputeSpec, DeviceShardComputer,
-                     NumpyShardComputer, ShardComputer, WorkerPlan,
-                     make_computer, worker_main)
+from .worker import ChaosSpec, WorkerPlan, worker_main
 
 __all__ = [
     "ShardEvent", "BatchRecord", "TraceRecording",
     "WorkerPool", "WorkerHandle", "ChaosSpec", "WorkerPlan", "worker_main",
-    "ShardComputer", "NumpyShardComputer", "DeviceShardComputer",
-    "ComputeSpec", "make_computer",
     "Transport", "LocalTransport", "SocketTransport", "TransportClosed",
     "make_transport", "ClusterConfig", "global_config",
     "ClusterBackend", "ClusterDispatch", "ReplayBackend",

@@ -9,14 +9,13 @@ measured on the master as each product arrives, not drawn from a model.
 
 :meth:`ClusterBackend.dispatch_batch` returns a :class:`ClusterDispatch`
 whose :meth:`~ClusterDispatch.next_event` stream feeds the unified serving
-loop: decoders update as shards arrive, answers emit mid-batch.  The
-dispatch is wired against the runtime's two seams: operands are published
-through the pool's :class:`~repro.cluster.transport.Transport` (shared
-memory locally, broadcast frames over TCP) and task messages carry an
-opaque operand reference the worker's endpoint resolves; which
-:class:`~repro.cluster.worker.ShardComputer` produces the products is the
-pool's ``compute`` recipe.  Every combination of
-``{numpy, device} × {local, socket}`` serves the same features.
+loop: decoders update as shards arrive, answers emit mid-batch.  Operands
+are published through the pool's
+:class:`~repro.cluster.transport.Transport` (shared memory locally,
+broadcast frames over TCP) and task messages carry an opaque operand
+reference the worker's endpoint resolves; the workers' products are the
+host einsum (:func:`~repro.cluster.worker.shard_products`).  Both
+transports serve the same features.
 
 **Speculative execution** (``speculate=True``): the dispatch can re-send a
 still-pending shard to a backup worker leased *outside* the active fleet
@@ -41,10 +40,8 @@ import numpy as np
 
 from ..obs import NULL_REGISTRY
 from ..serving.backends import ExecutionBackend, SimulatedBackend
-from .config import global_config
 from .events import BatchRecord, ShardEvent, TraceRecording
 from .pool import WorkerPool
-from .worker import COMPUTE_NAMES, ComputeSpec, make_computer
 
 __all__ = ["ClusterBackend", "ClusterDispatch", "ReplayBackend"]
 
@@ -352,9 +349,8 @@ class ClusterBackend(ExecutionBackend):
     pins ``r-1`` up-front copies of every shard — the classic replication
     baseline, no policy in the loop.
 
-    ``compute`` (``"numpy"`` | ``"device"``) and ``transport`` (``"local"``
-    | ``"socket"``; ``hosts`` overrides the socket listener addresses)
-    select the pool's two seams — any of the four combinations serves the
+    ``transport`` (``"local"`` | ``"socket"``; ``hosts`` overrides the
+    socket listener addresses) selects the pool's wire — either serves the
     full feature set.
     """
 
@@ -365,7 +361,7 @@ class ClusterBackend(ExecutionBackend):
                  chaos=None, seed: int = 0, record: bool = False,
                  grace: float = 2.0, sync_timeout: float = 60.0,
                  speculate: bool = False, replicate: int = 1,
-                 max_requeue: int = 3, compute=None, transport=None,
+                 max_requeue: int = 3, transport=None,
                  hosts=None, start_method: str = "spawn",
                  pool: WorkerPool | None = None, metrics=None):
         if grace <= 0 or sync_timeout <= 0:
@@ -374,7 +370,7 @@ class ClusterBackend(ExecutionBackend):
             raise ValueError(f"replicate must be >= 1; got {replicate}")
         self.pool = pool if pool is not None else WorkerPool(
             workers, spares=spares, chaos=chaos, seed=seed,
-            start_method=start_method, compute=compute, transport=transport,
+            start_method=start_method, transport=transport,
             hosts=hosts, metrics=metrics)
         self._owns_pool = pool is None
         # an adopted pool keeps its own registry unless we were handed one
@@ -441,52 +437,20 @@ class ReplayBackend(SimulatedBackend):
     """Replay a recorded cluster trace through the simulated product path.
 
     Products come from the *same* encode + contraction as the cluster
-    workers (bit-identical on the same host — pinned), and
-    ``draw_latencies`` replays the measured per-shard times batch by
+    workers (the full-batch einsum, bit-identical on the same host to
+    :func:`~repro.cluster.worker.shard_products`'s width-1 slices — pinned),
+    and ``draw_latencies`` replays the measured per-shard times batch by
     batch.  Serving a replay therefore reproduces a cluster run exactly,
     which is both the equivalence fixture and a debugging tool (re-serve a
     production trace under a different decoder/cache configuration).
-
-    ``compute`` mirrors the recorded run's compute seam: ``"numpy"``
-    (default) uses the simulated full-batch einsum — bit-identical to
-    :class:`~repro.cluster.worker.NumpyShardComputer`'s width-1 slices —
-    while ``"device"`` recomputes every per-shard product through the *same*
-    :class:`~repro.cluster.worker.DeviceShardComputer` path the workers
-    ran, so device-mode traces replay bit-identically too.
     """
 
     name = "replay"
 
-    def __init__(self, recording: TraceRecording, compute: str = "numpy",
-                 **sim_kw):
+    def __init__(self, recording: TraceRecording, **sim_kw):
         super().__init__(**sim_kw)
-        if compute not in COMPUTE_NAMES:
-            raise ValueError(f"unknown compute kind {compute!r}; valid: "
-                             f"{', '.join(COMPUTE_NAMES)}")
         self.recording = recording
-        self.compute = compute
-        self._computers: dict[int, object] = {}
         self._cursor = 0
-
-    def _computer_for(self, shard: int):
-        """One device computer per logical device index, mirroring the
-        pool's ``wid % host_device_count`` pinning (worker ``wid`` == shard
-        slot on the first lease)."""
-        count = max(1, global_config.host_device_count)
-        index = int(shard) % count
-        if index not in self._computers:
-            self._computers[index] = make_computer(
-                ComputeSpec.parse("device").for_worker(index))
-        return self._computers[index]
-
-    def compute_products(self, code, As, Bs,
-                         n_shards: int | None = None) -> np.ndarray:
-        if self.compute == "numpy":
-            return super().compute_products(code, As, Bs, n_shards)
-        E_A, E_B = self._encode_batch(code, As, Bs, n_shards)
-        cols = [self._computer_for(shard).shard_products(E_A, E_B, shard)
-                for shard in range(E_A.shape[1])]
-        return np.stack(cols, axis=1)
 
     def draw_latencies(self, rng: np.random.Generator,
                        N: int) -> np.ndarray:

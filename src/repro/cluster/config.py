@@ -1,15 +1,13 @@
 """Global cluster-runtime configuration (the alpa ``GlobalConfig`` idiom).
 
 One module-level :data:`global_config` instance holds every tunable of the
-cluster runtime's two seams — the **compute layer** (which
-:class:`~repro.cluster.worker.ShardComputer` a worker builds, how many
-virtual XLA devices a CPU host exposes, the device dtype) and the
-**transport layer** (which :class:`~repro.cluster.transport.Transport`
-carries tasks/operands/results, the socket host list, framing bounds).
-Options default from ``SAC_CLUSTER_*`` environment variables so CI jobs and
-multi-host launch scripts can flip them without threading keyword arguments
-through every constructor; explicit ``WorkerPool``/``ClusterBackend``
-keywords always win over the globals.
+cluster runtime's **transport layer**: which
+:class:`~repro.cluster.transport.Transport` carries tasks/operands/results,
+the socket host list, framing bounds.  Options default from
+``SAC_CLUSTER_*`` environment variables so CI jobs and multi-host launch
+scripts can flip them without threading keyword arguments through every
+constructor; explicit ``WorkerPool``/``ClusterBackend`` keywords always win
+over the globals.
 
 This module is imported by the multiprocessing spawn target, so it must
 stay stdlib-only — reading the config must never pay for jax.
@@ -36,25 +34,9 @@ def _env_float(name: str, default: float) -> float:
 
 
 class ClusterConfig:
-    """Global configuration of the cluster runtime's compute/transport seams."""
+    """Global configuration of the cluster runtime's transport seam."""
 
     def __init__(self):
-        ########## Options of the compute layer ##########
-        # which ShardComputer workers build: "numpy" | "device"
-        self.compute: str = _env_str("SAC_CLUSTER_COMPUTE", "numpy")
-        # virtual XLA devices per CPU host (xla_force_host_platform_
-        # device_count).  Each device-compute worker pins itself to
-        # ``devices()[wid % host_device_count]`` — one logical device per
-        # worker.  0 disables the flag injection (real accelerator hosts).
-        self.host_device_count: int = _env_int("SAC_CLUSTER_HOST_DEVICES", 8)
-        # dtype the device path computes in; numpy-vs-device pinning
-        # tolerances (tests/test_cluster.py, EXPERIMENTS.md) assume float32
-        self.device_dtype: str = _env_str("SAC_CLUSTER_DEVICE_DTYPE",
-                                          "float32")
-        # tri-state Pallas toggle for the kernel ops (None: TPU default)
-        self.use_pallas: bool | None = None
-
-        ########## Options of the transport layer ##########
         # which Transport carries the pool's traffic: "local" | "socket"
         self.transport: str = _env_str("SAC_CLUSTER_TRANSPORT", "local")
         # listener addresses of the socket transport — one listener per

@@ -26,13 +26,11 @@ growing past it needed somewhere for the extra workers to come from.
   ``shards_cancelled`` counts first-wins losers separately from
   ``shards_lost`` (shards that genuinely never arrived).
 
-The pool is wired against the runtime's two seams: the **transport**
+The pool is wired against the runtime's **transport**
 (:mod:`~repro.cluster.transport` — ``"local"`` pipes/shm or ``"socket"``
-TCP; every message, operand block and result crosses it) and the
-**compute** recipe (:class:`~repro.cluster.worker.ComputeSpec` — numpy or
-device shard products; the pool stamps each worker's logical device index
-at spawn).  Workers are daemon processes: a wedged master can die without
-leaving orphans, and CI jobs cannot be held hostage by a hung worker.
+TCP; every message, operand block and result crosses it).  Workers are
+daemon processes: a wedged master can die without leaving orphans, and CI
+jobs cannot be held hostage by a hung worker.
 """
 from __future__ import annotations
 
@@ -43,7 +41,7 @@ from dataclasses import dataclass, field
 
 from ..obs import NULL_REGISTRY
 from .transport import OperandHandle, Transport, make_transport
-from .worker import START_FAILED, ChaosSpec, ComputeSpec, worker_main
+from .worker import ChaosSpec, worker_main
 
 __all__ = ["WorkerPool", "WorkerHandle"]
 
@@ -86,9 +84,7 @@ class WorkerPool:
 
     ``transport`` selects the wire (``"local"`` | ``"socket"`` | a ready
     :class:`~repro.cluster.transport.Transport`; ``hosts`` overrides the
-    socket listener addresses) and ``compute`` the workers' shard computer
-    (``"numpy"`` | ``"device"`` | a
-    :class:`~repro.cluster.worker.ComputeSpec`); both default from
+    socket listener addresses), defaulting from
     :data:`~repro.cluster.config.global_config`.
     """
 
@@ -96,7 +92,6 @@ class WorkerPool:
                  chaos: ChaosSpec | str | None = None, seed: int = 0,
                  start_method: str = "spawn", ready_timeout: float = 60.0,
                  transport: Transport | str | None = None,
-                 compute: ComputeSpec | str | None = None,
                  hosts=None, metrics=None):
         if workers < 0 or spares < 0:
             raise ValueError(f"need workers >= 0 and spares >= 0; got "
@@ -110,7 +105,6 @@ class WorkerPool:
         self.metrics = metrics if metrics is not None else NULL_REGISTRY
         self.transport = make_transport(transport, ctx=self._ctx,
                                         hosts=hosts, metrics=self.metrics)
-        self.compute = ComputeSpec.parse(compute)
         self._active: dict[int, WorkerHandle] = {}
         self._spares: list[WorkerHandle] = []
         self._backups: dict[int, WorkerHandle] = {}   # speculative leases
@@ -181,8 +175,7 @@ class WorkerPool:
         channel, endpoint_arg = self.transport.connect(wid)
         proc = self._ctx.Process(
             target=worker_main,
-            args=(wid, endpoint_arg, self.chaos.plan_for(wid), self.seed,
-                  self.compute.for_worker(wid)),
+            args=(wid, endpoint_arg, self.chaos.plan_for(wid), self.seed),
             daemon=True, name=f"sac-worker-{wid}")
         proc.start()
         if endpoint_arg[0] == "local":
@@ -282,25 +275,13 @@ class WorkerPool:
         Dead *backup* workers are scrapped without replacement (and without
         counting ``shards_lost`` — their copies are duplicates whose primary
         may still deliver); the dispatch decides whether the shard needs a
-        fresh copy.  An active worker that exited because its shard computer
-        could not start raises ``RuntimeError`` instead.
+        fresh copy.
         """
         self._check_open()
         dead = []
         for wid, h in list(self._active.items()):
             if h.alive():
                 continue
-            if not h.ready:
-                h.proc.join(_JOIN_TIMEOUT)    # its channel closes first
-            if h.proc.exitcode == START_FAILED:
-                # a replacement would fail the same way
-                raise RuntimeError(
-                    f"worker {wid} could not start its shard computer (its "
-                    "traceback is on stderr).  With --compute device each "
-                    "worker process needs a JAX device of its own, and a "
-                    "TPU host lets one JAX process hold its chips: one JAX "
-                    "process per chip.  Serve on the chip with --backend "
-                    "device.")
             dead.append((wid, set(h.busy)))
             self._bump("crashed")
             self._bump("shards_lost", len(h.busy))

@@ -70,12 +70,32 @@ class CDCCode:
         """``(G_A, G_B)`` each of shape ``(N, K)``."""
         raise NotImplementedError
 
-    def encode(self, A_blocks, B_blocks):
-        """Encoded per-worker operands ``(E_A: (N,Nx,bz), E_B: (N,bz,Ny))``."""
-        G_A, G_B = self.generator()
-        E_A = np.einsum("nk,kij->nij", G_A, np.asarray(A_blocks))
-        E_B = np.einsum("nk,kij->nij", G_B, np.asarray(B_blocks))
-        return E_A, E_B
+    def encode(self, A_blocks, B_blocks, *, n_shards: int | None = None,
+               out=None):
+        """Encoded per-worker operands ``(E_A: (n,Nx,bz), E_B: (n,bz,Ny))``.
+
+        Each operand is one BLAS GEMM over its flattened blocks,
+        ``G[:n] @ blocks.reshape(K, -1)``, in the generator's precision
+        (complex128 for complex points, float64 for real ones).
+        ``n_shards`` keeps the first ``n`` workers (default ``N``): the
+        generator rows are sliced *before* the encode, so a shrunk fleet
+        saves the encode work too.  ``out``, a pair of C-contiguous arrays
+        of the output shapes, receives the result in place of new arrays.
+        """
+        n = self.N if n_shards is None else n_shards
+        if not 1 <= n <= self.N:
+            raise ValueError(f"need 1 <= n_shards <= N={self.N}; got "
+                             f"{n_shards}")
+        encoded = []
+        for G, blocks, o in zip(self.generator(), (A_blocks, B_blocks),
+                                (None, None) if out is None else out):
+            blocks = np.asarray(blocks)
+            if o is None:
+                o = np.empty((n,) + blocks.shape[1:],
+                             np.result_type(G, blocks))
+            np.matmul(G[:n], blocks.reshape(self.K, -1), out=o.reshape(n, -1))
+            encoded.append(o)
+        return tuple(encoded)
 
     @staticmethod
     def worker_products(E_A, E_B):

@@ -49,16 +49,14 @@ scale-out path), ``--spares`` keeps warm spares after releases, ``--chaos``
 injects reproducible perturbations (``sleep:LO:HI``, ``slow:C:DELAY``,
 ``crash:C``, ``hang:C``), ``--record PATH`` saves the measured completion
 trace, and ``--replay PATH`` re-serves a recorded trace through the
-simulated product path (bit-identical decode outputs).  ``--compute
-{numpy,device}`` picks the shard-product implementation each worker runs
-(numpy einsum, or the Pallas kernel ops on the worker's pinned XLA
-device); ``--transport {local,socket}`` picks the master<->worker plumbing
-(pipes + shared memory, or framed TCP with ``--hosts`` listener
-addresses).  Every feature works in all four compute x transport combos,
-and a device-mode trace replays with ``--replay PATH --compute device``.
-With ``--autotune --scale-out``, a drift-detected tail worsening lets the
-policy *grow* the fleet (``--N-options`` entries above ``--N`` are allowed
-on the cluster backend)::
+simulated product path (bit-identical decode outputs).  ``--transport
+{local,socket}`` picks the master<->worker plumbing (pipes + shared
+memory, or framed TCP with ``--hosts`` listener addresses); every feature
+works over both.  The workers compute on the host: products on the chip
+go through ``--backend device``.  With ``--autotune --scale-out``, a
+drift-detected tail worsening lets the policy *grow* the fleet
+(``--N-options`` entries above ``--N`` are allowed on the cluster
+backend)::
 
     PYTHONPATH=src python -m repro.launch.serve --backend cluster \
         --code matdot --K 2 --N 4 --workers 4 --spares 1 \
@@ -233,11 +231,6 @@ def build_parser() -> argparse.ArgumentParser:
     fleet.add_argument("--fleet", type=int, default=None,
                        help="dispatch only the first N encode shards of the "
                        "starting code (operator override)")
-    fleet.add_argument("--compute", default="numpy",
-                       choices=("numpy", "device"),
-                       help="cluster/replay: shard products via numpy einsum "
-                       "or the Pallas kernel ops on each worker's pinned "
-                       "device")
     fleet.add_argument("--transport", default="local",
                        choices=("local", "socket"),
                        help="cluster: master<->worker plumbing — pipes + "
@@ -372,13 +365,6 @@ def _collect_problems(args) -> list[str]:
     if args.hosts is not None and args.transport != "socket":
         problems.append("--hosts requires --transport socket (the local "
                         "transport has no listener addresses)")
-    # device compute runs on the cluster's worker processes, or during
-    # replay (ReplayBackend recomputes each shard through the same kernel
-    # path) — the modeled backends have their own product story
-    if (args.compute != "numpy" and args.backend != "cluster"
-            and args.replay is None):
-        problems.append("--compute device requires --backend cluster or "
-                        "--replay PATH (re-serving a device-mode trace)")
     if args.replay is not None and args.backend != "sim":
         problems.append(f"--replay re-serves the trace through the "
                         f"simulated product path; drop --backend "
@@ -468,10 +454,8 @@ def _effective_config(args, deadlines) -> str:
     if args.backend == "cluster":
         cfg.update(workers=args.workers, spares=args.spares,
                    chaos=args.chaos, grace=args.grace,
-                   compute=args.compute, transport=args.transport)
-    if args.replay is not None:
-        cfg.update(compute=args.compute)
-    elif args.backend == "device":
+                   transport=args.transport)
+    if args.backend == "device":
         # which implementation runs the products, and on what: the jnp
         # oracle stands in for Pallas off the TPU, and says so here
         from repro.serving.backends import device_info
@@ -603,8 +587,7 @@ def run_serve(args) -> ServeReport:
             recording = TraceRecording.load(args.replay)
         except (OSError, ValueError, KeyError) as e:
             raise SystemExit(f"[serve] --replay {args.replay}: {e}")
-        backend = make_backend("replay", recording=recording,
-                               compute=args.compute)
+        backend = make_backend("replay", recording=recording)
     elif args.backend == "cluster":
         hosts = (tuple(h.strip() for h in args.hosts.split(","))
                  if args.hosts is not None else None)
@@ -614,8 +597,7 @@ def run_serve(args) -> ServeReport:
                 chaos=args.chaos, seed=args.seed,
                 record=args.record is not None, grace=args.grace,
                 speculate=args.speculate, replicate=args.replicate,
-                max_requeue=args.max_requeue, compute=args.compute,
-                transport=args.transport, hosts=hosts, metrics=registry)
+                max_requeue=args.max_requeue, transport=args.transport, hosts=hosts, metrics=registry)
         except ValueError as e:
             raise SystemExit(f"[serve] invalid arguments:\n  {e}")
     else:
@@ -866,7 +848,7 @@ def _render_report(rep: ServeReport) -> None:
     extra = ""
     if cd["backend"] == "cluster":
         extra = (f" workers={cfg['workers']} spares={cfg['spares']} "
-                 f"chaos={cfg['chaos'] or 'none'} compute={cfg['compute']} "
+                 f"chaos={cfg['chaos'] or 'none'} "
                  f"transport={cfg['transport']} (deadlines are wall-clock "
                  "seconds)")
     print(f"[serve] code={cd['name']} K={cd['K']} N={cd['N']} "

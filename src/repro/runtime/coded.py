@@ -33,7 +33,7 @@ from ..core.codes.base import CDCCode
 from ..kernels.coded_matmul.ops import worker_products
 
 __all__ = ["decode_weight_vector", "distributed_coded_matmul",
-           "coded_contraction", "encode_operands"]
+           "coded_contraction"]
 
 
 # ------------------------------------------------------------ host control
@@ -72,11 +72,6 @@ def decode_weight_vector(code: CDCCode, order: np.ndarray, m: int,
                 "decode host-side via code.decode.")
         full = full.real
     return full
-
-
-def encode_operands(code: CDCCode, A_blocks, B_blocks):
-    """Host-side f64 encode → per-worker operand stacks (N, ..., ...)."""
-    return code.encode(np.asarray(A_blocks), np.asarray(B_blocks))
 
 
 # ------------------------------------------------------- shard_map job path

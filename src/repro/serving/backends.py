@@ -152,30 +152,23 @@ class ExecutionBackend:
     def _encode_batch(code: CDCCode, As, Bs, n_shards: int | None = None):
         """``(E_A: (B,n,Nx,bz), E_B: (B,n,bz,Ny))`` for the whole batch.
 
-        Each operand of each request is one BLAS GEMM over its flattened
-        blocks, ``(n, K) @ (K, Nx·bz)``, in the generator's precision
-        (complex128 for complex points, float64 for real ones), written
-        straight into the batch's output.  With ``n_shards`` the generator
-        rows are sliced *before* the encode — a shrunk fleet saves the
-        encode work too, not just the worker occupancy.
+        Each request is split into block buffers shared by the batch and
+        encoded by :meth:`CDCCode.encode` (one GEMM per operand, in the
+        generator's precision) straight into the batch's output.
         """
-        G_A, G_B = code.generator()
-        if n_shards is not None:
-            if not 1 <= n_shards <= code.N:
-                raise ValueError(f"need 1 <= n_shards <= N={code.N}; got "
-                                 f"{n_shards}")
-            G_A, G_B = G_A[:n_shards], G_B[:n_shards]
         As = [np.asarray(A) for A in As]
         Bs = [np.asarray(B) for B in Bs]
-        (Nx, Nz), (_, Ny), K, n = As[0].shape, Bs[0].shape, code.K, len(G_A)
+        (Nx, Nz), (_, Ny), K = As[0].shape, Bs[0].shape, code.K
+        n = code.N if n_shards is None else n_shards
+        G_A, G_B = code.generator()            # for the output dtypes
         E_A = np.empty((len(As), n, Nx, Nz // K), np.result_type(G_A, *As))
         E_B = np.empty((len(Bs), n, Nz // K, Ny), np.result_type(G_B, *Bs))
         A_blocks = np.empty((K,) + E_A.shape[2:], E_A.dtype)
         B_blocks = np.empty((K,) + E_B.shape[2:], E_B.dtype)
         for r, (A, B) in enumerate(zip(As, Bs, strict=True)):
             split_contraction(A, B, K, out=(A_blocks, B_blocks))
-            np.matmul(G_A, A_blocks.reshape(K, -1), out=E_A[r].reshape(n, -1))
-            np.matmul(G_B, B_blocks.reshape(K, -1), out=E_B[r].reshape(n, -1))
+            code.encode(A_blocks, B_blocks, n_shards=n_shards,
+                        out=(E_A[r], E_B[r]))
         return E_A, E_B
 
 
@@ -356,14 +349,14 @@ class DeviceBackend(ExecutionBackend):
         """
         import jax.numpy as jnp
 
-        from ..runtime.coded import distributed_coded_matmul, encode_operands
+        from ..runtime.coded import distributed_coded_matmul
         if np.iscomplexobj(np.asarray(weights)):
             raise ValueError("complex decode weights cannot enter the real "
                              "mesh job path; use a real-point code")
         dt = jnp.float32 if dtype is None else dtype
         A_blocks, B_blocks = split_contraction(np.asarray(A), np.asarray(B),
                                                code.K)
-        E_A, E_B = encode_operands(code, A_blocks, B_blocks)
+        E_A, E_B = code.encode(A_blocks, B_blocks)
         return distributed_coded_matmul(
             jnp.asarray(E_A, dt), jnp.asarray(E_B, dt),
             jnp.asarray(np.asarray(weights), dt), mesh, axis=axis,

@@ -53,6 +53,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..core.codes.base import CDCCode
+from ..core.partition import split_contraction
 from ..obs import NULL_BURN, NULL_FLIGHT, NULL_REGISTRY, NULL_SAMPLER, \
     NULL_TRACER
 from .backends import ExecutionBackend, SimulatedBackend
@@ -672,8 +673,8 @@ class MasterScheduler:
         if eps is None or eps <= np.finfo(np.float64).eps:
             return False
         workers = dec.completed
-        E_A, E_B = ExecutionBackend._encode_batch(dec.code, [A], [B])
-        E_A, E_B = E_A[0, workers], E_B[0, workers]
+        E_A, E_B = dec.code.encode(*split_contraction(A, B, dec.code.K))
+        E_A, E_B = E_A[workers], E_B[workers]
         exact = np.matmul(E_A, E_B)
         bound = (E_A.shape[-1] + 2) * eps * (
             np.linalg.norm(E_A, axis=(1, 2)) * np.linalg.norm(E_B, axis=(1, 2)))

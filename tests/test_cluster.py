@@ -446,45 +446,10 @@ def test_replicate_pins_upfront_copies():
     assert time.monotonic() - t0 < 60.0
 
 
-# ------------------------------------------- compute seam: device vs numpy
-
-DEVICE_FAMILIES = [
-    ("matdot_complex", lambda: MatDotCode(2, 6, x_complex(6, 0.1)), 1e-5),
-    ("gsac_complex",
-     lambda: GroupSACCode(2, 6, x_complex(6, 0.1), [1, 1]), 1e-5),
-    ("lsac_ortho_real",
-     lambda: LayerSACCode(2, 6, base="ortho", eps=6.25e-3), 1e-5),
-]
-
-
-@pytest.mark.parametrize("family,make_code,tol", DEVICE_FAMILIES,
-                         ids=[t[0] for t in DEVICE_FAMILIES])
-def test_device_computer_matches_numpy_per_code_family(family, make_code,
-                                                       tol):
-    """The compute seam's accuracy contract, pinned per code family: every
-    shard's device product (float32 kernel ops; complex operands via the
-    4-real-GEMM expansion, so the device never sees a complex dtype) stays
-    within relative tolerance of the numpy einsum."""
-    from repro.cluster import ComputeSpec, make_computer
-    from repro.serving.backends import ExecutionBackend
-    code = make_code()
-    rng = np.random.default_rng(23)
-    As, Bs = zip(*_reqs(rng, 2))
-    E_A, E_B = ExecutionBackend._encode_batch(code, As, Bs)
-    base = make_computer(ComputeSpec.parse("numpy"))
-    for shard in range(code.N):
-        want = base.shard_products(E_A, E_B, shard)
-        dev = make_computer(ComputeSpec.parse("device").for_worker(shard))
-        got = dev.shard_products(E_A, E_B, shard)
-        assert got.shape == want.shape
-        rel = np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30)
-        assert rel < tol, (family, shard, rel)
-
-
 # --------------------------------------------- transport seam: serve parity
 
 def test_socket_transport_crash_loss_and_replay_bit_identity():
-    """numpy x socket: the TCP transport serves the same crash semantics as
+    """The TCP transport serves the same crash semantics as
     the pipes (worker 0's EOF surfaces as a clean shard loss, the pool
     heals by replacement) and its measured trace replays bit-identically."""
     t0 = time.monotonic()
@@ -504,44 +469,6 @@ def test_socket_transport_crash_loss_and_replay_bit_identity():
     replay = _serve(MasterScheduler(code, ReplayBackend(rec), cfg), reqs)
     assert live == replay
     assert time.monotonic() - t0 < 60.0
-
-
-def test_device_compute_serve_and_replay_bit_identity():
-    """device x socket — both seams stretched at once: Pallas kernel-op
-    products on each worker's pinned device, shipped over TCP.  The live
-    answers replay bit-identically only through a device-mode
-    ``ReplayBackend``; the numpy replay differs in the float32 low bits,
-    proving the recorded trace pins the compute seam too."""
-    t0 = time.monotonic()
-    code = MatDotCode(K, N, x_complex(N, 0.1))
-    rng = np.random.default_rng(31)
-    reqs = _reqs(rng, 2)
-    cfg = ServeConfig(deadlines=(1.0,), stream=True, batch_size=2, seed=0)
-    with ClusterBackend(workers=N, chaos="sleep:0.005:0.02", seed=8,
-                        record=True, compute="device",
-                        transport="socket") as be:
-        live = _serve(MasterScheduler(code, be, cfg), reqs)
-        rec = be.recording
-    dev = _serve(MasterScheduler(code, ReplayBackend(rec, compute="device"),
-                                 cfg), reqs)
-    assert live == dev
-    host = _serve(MasterScheduler(code, ReplayBackend(rec), cfg), reqs)
-    assert live != host
-    assert time.monotonic() - t0 < 120.0
-
-
-def test_device_worker_start_failure_is_a_prompt_error():
-    """A device worker that cannot start its computer (on a TPU host: a
-    second process asking for a held chip) fails the lease at once, naming
-    the cause, instead of waiting out ``ready_timeout`` or losing shards."""
-    from repro.cluster import ComputeSpec
-    broken = ComputeSpec(kind="device", dtype="no-such-dtype")
-    t0 = time.monotonic()
-    with WorkerPool(0, seed=0, compute=broken, ready_timeout=60.0) as pool:
-        with pytest.raises(RuntimeError,
-                           match="one JAX process per chip.*--backend device"):
-            pool.lease(2)
-    assert time.monotonic() - t0 < 30.0
 
 
 def test_transport_releases_operands_on_crash_and_teardown():

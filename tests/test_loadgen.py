@@ -304,9 +304,6 @@ def test_summarize_load_counts_and_percentiles():
         "repro.cluster.transport", fromlist=["make_transport"]
     ).make_transport("pigeon"), id="transport"),
     pytest.param(lambda: __import__(
-        "repro.cluster.worker", fromlist=["ComputeSpec"]
-    ).ComputeSpec.parse("quantum"), id="compute"),
-    pytest.param(lambda: __import__(
         "repro.cluster.worker", fromlist=["ChaosSpec"]
     ).ChaosSpec.parse("meteor:1"), id="chaos"),
 ])

@@ -102,7 +102,7 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.core import MatDotCode, chebyshev_roots
     from repro.runtime.coded import (distributed_coded_matmul,
-                                     decode_weight_vector, encode_operands)
+                                     decode_weight_vector)
     from repro.core.partition import split_contraction
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ("data", "model"))
@@ -111,7 +111,7 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
     A = rng.standard_normal((16, 48)); B = rng.standard_normal((48, 12))
     code = MatDotCode(K, N, chebyshev_roots(N))
     Ab, Bb = split_contraction(A, B, K)
-    E_A, E_B = encode_operands(code, Ab, Bb)
+    E_A, E_B = code.encode(Ab, Bb)
     out = {}
     for m in (code.recovery_threshold, N):
         w = decode_weight_vector(code, np.arange(N), m)

@@ -576,11 +576,14 @@ def test_encode_batch_matches_generator(family, batch, n_shards):
     n = code.N if n_shards is None else n_shards
     dtype = np.complex128 if family == "gsac_complex" else np.float64
     splits = [split_contraction(A, B, K) for A, B in zip(As, Bs)]
+    encoded = [code.encode(*split, n_shards=n_shards) for split in splits]
     for side, (E, G) in enumerate(((E_A, G_A), (E_B, G_B))):
         assert E.dtype == G.dtype == dtype
         assert E.flags.c_contiguous
         assert E.shape == (batch, n) + splits[0][side].shape[1:]
         for r in range(batch):
+            # one host encoder: the batch encode is the code's own
+            np.testing.assert_array_equal(E[r], encoded[r][side])
             blocks = splits[r][side]
             want = np.zeros((n,) + blocks.shape[1:], dtype)
             for i in range(n):
@@ -646,29 +649,43 @@ def test_device_backend_encodes_once_per_batch_through_the_hook():
 
 # ------------------------------------------------------------- device backend
 
-def test_device_backend_matches_simulated_real():
-    from repro.serving import DeviceBackend
-    code = MatDotCode(4, 8, chebyshev_roots(8))
-    rng = np.random.default_rng(3)
-    As = [rng.standard_normal((16, 32)) for _ in range(2)]
-    Bs = [rng.standard_normal((32, 8)) for _ in range(2)]
-    want = SimulatedBackend().compute_products(code, As, Bs)
-    got = DeviceBackend(use_pallas=False).compute_products(code, As, Bs)
-    assert got.shape == want.shape == (2, 8, 16, 8)
-    rel = np.linalg.norm(got - want) / np.linalg.norm(want)
-    assert rel < 1e-4                           # f32 device path
+DEVICE_FAMILIES = {
+    # family: (code, (rows, inner, cols), batch, seed, relative tolerance)
+    "matdot_real": (lambda: MatDotCode(4, 8, chebyshev_roots(8)),
+                    (16, 32, 8), 2, 3, 1e-4),
+    "matdot_reim": (lambda: MatDotCode(3, 8, x_complex(8, 0.5)),
+                    (8, 24, 8), 1, 4, 1e-4),
+    "matdot_complex": (lambda: MatDotCode(2, 6, x_complex(6, 0.1)),
+                       (8, 8, 8), 2, 23, 1e-5),
+    "gsac_complex": (lambda: GroupSACCode(2, 6, x_complex(6, 0.1), [1, 1]),
+                     (8, 8, 8), 2, 23, 1e-5),
+    "lsac_ortho_real": (lambda: LayerSACCode(2, 6, base="ortho",
+                                             eps=6.25e-3),
+                        (8, 8, 8), 2, 23, 1e-5),
+}
 
 
-def test_device_backend_complex_reim_expansion():
+@pytest.mark.parametrize("family", list(DEVICE_FAMILIES))
+def test_device_backend_matches_simulated(family):
+    """The device path's accuracy contract, per code family: its float32
+    products (complex points through the re/im 4-real-GEMM expansion, so
+    the device never sees a complex dtype) stay, shard by shard, within
+    relative tolerance of the simulated backend's float64 einsum."""
     from repro.serving import DeviceBackend
-    code = MatDotCode(3, 8, x_complex(8, 0.5))
-    rng = np.random.default_rng(4)
-    As, Bs = [rng.standard_normal((8, 24))], [rng.standard_normal((24, 8))]
+    make_code, (rows, inner, cols), batch, seed, tol = \
+        DEVICE_FAMILIES[family]
+    code = make_code()
+    rng = np.random.default_rng(seed)
+    As = [rng.standard_normal((rows, inner)) for _ in range(batch)]
+    Bs = [rng.standard_normal((inner, cols)) for _ in range(batch)]
     want = SimulatedBackend().compute_products(code, As, Bs)
     got = DeviceBackend(use_pallas=False).compute_products(code, As, Bs)
-    assert np.iscomplexobj(got)
-    rel = np.linalg.norm(got - want) / np.linalg.norm(want)
-    assert rel < 1e-4
+    assert got.shape == want.shape == (batch, code.N, rows, cols)
+    assert np.iscomplexobj(got) == np.iscomplexobj(code.eval_points)
+    for shard in range(code.N):
+        rel = np.linalg.norm(got[:, shard] - want[:, shard]) \
+            / np.linalg.norm(want[:, shard])
+        assert rel < tol, (family, shard, rel)
 
 
 def test_device_decode_on_mesh_exact():

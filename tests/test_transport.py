@@ -74,10 +74,25 @@ def test_msg_roundtrip_arrays_and_tuples():
         b.close()
 
 
+def _roundtrip(send, recv, payloads):
+    """What ``recv`` reads back while ``send`` writes ``payloads`` from a
+    thread: frames past the kernel socket buffer need a live reader."""
+    sender = threading.Thread(target=lambda: [send(p) for p in payloads],
+                              daemon=True)
+    sender.start()
+    try:
+        return [recv() for _ in payloads]
+    finally:
+        sender.join(timeout=5.0)
+        assert not sender.is_alive()
+
+
 def test_frame_roundtrip_property_random_sizes():
     """Property (hypothesis): any sequence of message sizes — 0-byte and
     >64 KiB included — round-trips in order through both transports' wire
-    formats: the socket frame stream and the local duplex pipe."""
+    formats: the socket frame stream and the local duplex pipe.  The
+    frames are written from a thread while they are read, since several
+    of them can exceed the socket buffer."""
     st = pytest.importorskip("hypothesis.strategies")
     hypothesis = pytest.importorskip("hypothesis")
 
@@ -94,17 +109,15 @@ def test_frame_roundtrip_property_random_sizes():
                     for n in sizes]
         a, b = _pair()
         try:
-            for p in payloads:
-                send_frame(a, p)
-            assert [recv_frame(b) for _ in payloads] == payloads
+            assert _roundtrip(lambda p: send_frame(a, p),
+                              lambda: recv_frame(b), payloads) == payloads
         finally:
             a.close()
             b.close()
         parent, child = multiprocessing.get_context("spawn").Pipe()
         try:
-            for p in payloads:
-                parent.send(("task", p))
-            assert [child.recv()[1] for _ in payloads] == payloads
+            assert _roundtrip(lambda p: parent.send(("task", p)),
+                              lambda: child.recv()[1], payloads) == payloads
         finally:
             parent.close()
             child.close()
