@@ -31,7 +31,10 @@ built from, and everything else speaks ``dispatch_batch``.
 """
 from __future__ import annotations
 
+import contextlib
+import math
 import threading
+import weakref
 
 import numpy as np
 
@@ -155,21 +158,107 @@ class ExecutionBackend:
         Each request is split into block buffers shared by the batch and
         encoded by :meth:`CDCCode.encode` (one GEMM per operand, in the
         generator's precision) straight into the batch's output.
+
+        All four buffers come from the module's :class:`_EncodeBuffers`.
+        The block buffers go back to it before this returns.  ``E_A`` and
+        ``E_B`` belong to the caller from then on: a caller that hands them
+        back (only :class:`DeviceBackend`, once its cast has read them)
+        lets a later batch encode into the same memory; any other caller
+        keeps them, and they are never written again.
         """
         As = [np.asarray(A) for A in As]
         Bs = [np.asarray(B) for B in Bs]
         (Nx, Nz), (_, Ny), K = As[0].shape, Bs[0].shape, code.K
         n = code.N if n_shards is None else n_shards
         G_A, G_B = code.generator()            # for the output dtypes
-        E_A = np.empty((len(As), n, Nx, Nz // K), np.result_type(G_A, *As))
-        E_B = np.empty((len(Bs), n, Nz // K, Ny), np.result_type(G_B, *Bs))
-        A_blocks = np.empty((K,) + E_A.shape[2:], E_A.dtype)
-        B_blocks = np.empty((K,) + E_B.shape[2:], E_B.dtype)
-        for r, (A, B) in enumerate(zip(As, Bs, strict=True)):
-            split_contraction(A, B, K, out=(A_blocks, B_blocks))
-            code.encode(A_blocks, B_blocks, n_shards=n_shards,
-                        out=(E_A[r], E_B[r]))
+        take = _ENCODE_BUFFERS.take
+        E_A = take("E_A", (len(As), n, Nx, Nz // K), np.result_type(G_A, *As))
+        E_B = take("E_B", (len(Bs), n, Nz // K, Ny), np.result_type(G_B, *Bs))
+        A_blocks = take("A_blocks", (K,) + E_A.shape[2:], E_A.dtype)
+        B_blocks = take("B_blocks", (K,) + E_B.shape[2:], E_B.dtype)
+        try:
+            for r, (A, B) in enumerate(zip(As, Bs, strict=True)):
+                split_contraction(A, B, K, out=(A_blocks, B_blocks))
+                code.encode(A_blocks, B_blocks, n_shards=n_shards,
+                            out=(E_A[r], E_B[r]))
+        finally:
+            _ENCODE_BUFFERS.give_back(A_blocks=A_blocks, B_blocks=B_blocks)
         return E_A, E_B
+
+
+class _EncodeBuffers:
+    """Host buffers of the batch encode, kept across batches.
+
+    Each role (``E_A``, ``E_B`` and the two block buffers) and dtype has a
+    list of free flat buffers.  :meth:`take` lends one out as a
+    C-contiguous prefix view of the shape asked for, or allocates a new
+    one when none is free or the free one is too small (the small one is
+    dropped, so a role's buffer only grows); :meth:`give_back` returns a
+    lent buffer to its list.  A lent buffer that is never given back stays
+    its taker's, so a caller that keeps its encoded operands never finds
+    them overwritten.  Once a batch of the largest size has run, a batch
+    whose buffers are given back touches no fresh memory: on a host
+    without transparent huge pages the first touch of a freshly mapped
+    output cost more than the GEMM that fills it, and its release as much
+    again.
+
+    The buffers live in the module because :meth:`ExecutionBackend.
+    _encode_batch` is a static hook that subclasses call as a plain
+    function.  :meth:`tally` counts, on the calling thread, the buffers
+    taken and the bytes allocated while it is open, which is how a backend
+    counts the encodes that run through such an override.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._free: dict[tuple[str, np.dtype], list[np.ndarray]] = {}
+        # (role, id) of every buffer out on loan; a buffer its taker drops
+        # leaves the map with it
+        self._lent = weakref.WeakValueDictionary()
+        self._local = threading.local()
+
+    def take(self, role: str, shape: tuple[int, ...], dtype) -> np.ndarray:
+        """A C-contiguous ``shape`` view of a ``role`` buffer, lent out."""
+        dt = np.dtype(dtype)
+        size = math.prod(shape)
+        grown = 0
+        with self._lock:
+            free = self._free.get((role, dt))
+            buf = free.pop() if free else None
+            if buf is None or buf.size < size:
+                buf = np.empty(size, dt)
+                grown = buf.nbytes
+            self._lent[role, id(buf)] = buf
+        tally = getattr(self._local, "tally", None)
+        if tally is not None:
+            tally[0] += 1
+            tally[1] += grown
+        return buf[:size].reshape(shape)
+
+    def give_back(self, **views: np.ndarray) -> None:
+        """Return the buffers under ``views`` (role=view) to their lists.
+
+        A view of a buffer that is not out on loan under that role, such
+        as an array an overriding encode made itself, is left alone.
+        """
+        with self._lock:
+            for role, view in views.items():
+                buf = view.base
+                if buf is not None and \
+                        self._lent.pop((role, id(buf)), None) is buf:
+                    self._free.setdefault((role, buf.dtype), []).append(buf)
+
+    @contextlib.contextmanager
+    def tally(self):
+        """``[buffers taken, bytes allocated]`` on this thread, while open."""
+        tally = self._local.tally = [0, 0]
+        try:
+            yield tally
+        finally:
+            self._local.tally = None
+
+
+_ENCODE_BUFFERS = _EncodeBuffers()
 
 
 class SimulatedBackend(ExecutionBackend):
@@ -223,6 +312,12 @@ class DeviceBackend(ExecutionBackend):
     ``metrics`` (a :class:`~repro.obs.MetricsRegistry`) receives the phase
     spans and byte counters of :meth:`compute_products`.
 
+    The batch is encoded into the module's encode buffers (see
+    :meth:`ExecutionBackend._encode_batch`) and hands its ``E_A`` and
+    ``E_B`` back to them as soon as its cast has read them, so the next
+    batch encodes into the same memory; ``serve.encode.staged`` counts the
+    encodes that needed no new buffer and ``serve.encode.stage_bytes`` the
+    bytes allocated for them, also through a subclass's ``_encode_batch``.
     The encoded operands are cast into staging buffers that the backend
     keeps across batches (see :meth:`_cast`); a lock orders the batches of
     a backend shared across threads from the cast until the device has
@@ -245,6 +340,9 @@ class DeviceBackend(ExecutionBackend):
         self.metrics = metrics if metrics is not None else NULL_REGISTRY
         self._c_h2d = self.metrics.counter("serve.h2d_bytes")
         self._c_d2h = self.metrics.counter("serve.d2h_bytes")
+        self._c_encode_staged = self.metrics.counter("serve.encode.staged")
+        self._c_encode_bytes = self.metrics.counter(
+            "serve.encode.stage_bytes")
         self._c_staged = self.metrics.counter("serve.cast.staged")
         self._c_stage_bytes = self.metrics.counter("serve.cast.stage_bytes")
         self._stage: list[np.ndarray] = []   # one flat buffer per plane
@@ -268,8 +366,13 @@ class DeviceBackend(ExecutionBackend):
         from ..kernels.coded_matmul.ops import (worker_products,
                                                 worker_products_complex)
         span = self.metrics.span
-        with span("serve.encode"):
+        with span("serve.encode"), _ENCODE_BUFFERS.tally() as tally:
             E_A, E_B = self._encode_batch(code, As, Bs, n_shards)
+        taken, grown = tally
+        if grown:
+            self._c_encode_bytes.inc(grown)
+        elif taken:
+            self._c_encode_staged.inc()
         B, N = E_A.shape[:2]
         ea = E_A.reshape((B * N,) + E_A.shape[2:])
         eb = E_B.reshape((B * N,) + E_B.shape[2:])
@@ -281,6 +384,8 @@ class DeviceBackend(ExecutionBackend):
         with self._lock:
             with span("serve.cast"):
                 host = self._cast(planes)
+            # nothing reads the encoded operands after the cast
+            _ENCODE_BUFFERS.give_back(E_A=E_A, E_B=E_B)
             with span("serve.h2d"):
                 dev = jax.device_put(host)
             self._c_h2d.inc(sum(x.nbytes for x in host))

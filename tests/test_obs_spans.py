@@ -196,6 +196,75 @@ def test_staging_counters_are_null_without_a_registry():
     assert NULL_REGISTRY.snapshot()["counters"] == {}
 
 
+class StaticEncode(DeviceBackend):
+    """Wraps the encode hook as the benchmark's timer does: a method that
+    calls ``DeviceBackend._encode_batch`` as a plain function."""
+
+    def _encode_batch(self, code, As, Bs, n_shards=None):
+        return DeviceBackend._encode_batch(code, As, Bs, n_shards)
+
+
+def _encode_bytes(code, n):
+    """Bytes of ``E_A``, ``E_B`` and the two block buffers of a batch."""
+    bz = INNER // code.K
+    itemsize = 16 if np.iscomplexobj(code.eval_points) else 8
+    return itemsize * (n * code.N + code.K) * (ROWS * bz + bz * COLS)
+
+
+@pytest.mark.parametrize("backend_cls", [DeviceBackend, StaticEncode])
+@pytest.mark.parametrize("kind", ["real", "complex"])
+def test_encode_counters_grow_once_then_count_reuse(kind, backend_cls,
+                                                    encode_buffers):
+    code = _codes()[kind]
+    reg = MetricsRegistry()
+    backend = backend_cls(use_pallas=False, metrics=reg)
+    backend.compute_products(code, *_operands(4))
+    counters = reg.snapshot()["counters"]
+    assert counters["serve.encode.stage_bytes"] == _encode_bytes(code, 4)
+    assert counters["serve.encode.staged"] == 0
+    for n in (4, 2, 1):
+        backend.compute_products(code, *_operands(n, seed=n))
+    counters = reg.snapshot()["counters"]
+    assert counters["serve.encode.stage_bytes"] == _encode_bytes(code, 4)
+    assert counters["serve.encode.staged"] == 3
+
+
+def test_encode_counters_are_null_without_a_registry(encode_buffers):
+    backend = StaticEncode(use_pallas=False)
+    for n in (2, 1):
+        backend.compute_products(_codes()["complex"], *_operands(n))
+    assert backend._c_encode_staged is \
+        NULL_REGISTRY.counter("serve.encode.staged")
+    assert backend._c_encode_bytes is \
+        NULL_REGISTRY.counter("serve.encode.stage_bytes")
+    assert NULL_REGISTRY.snapshot()["counters"] == {}
+
+
+def test_encode_counters_skip_an_encode_off_the_buffers(encode_buffers):
+    """An override that encodes into arrays of its own takes no buffer:
+    nothing is counted, nothing enters the pool on hand-back, and the
+    products are the program's."""
+    from repro.core import split_contraction
+
+    class OwnArrays(DeviceBackend):
+        def _encode_batch(self, code, As, Bs, n_shards=None):
+            E = [code.encode(*split_contraction(A, B, code.K),
+                             n_shards=n_shards) for A, B in zip(As, Bs)]
+            return tuple(np.stack(side) for side in zip(*E))
+
+    code = _codes()["real"]
+    reg = MetricsRegistry()
+    As, Bs = _operands(3, seed=4)
+    got = OwnArrays(use_pallas=False, metrics=reg).compute_products(
+        code, As, Bs)
+    counters = reg.snapshot()["counters"]
+    assert counters.get("serve.encode.stage_bytes", 0) == 0
+    assert counters.get("serve.encode.staged", 0) == 0
+    assert encode_buffers._free == {}
+    np.testing.assert_array_equal(
+        got, DeviceBackend(use_pallas=False).compute_products(code, As, Bs))
+
+
 def test_backend_shared_across_threads_keeps_each_batch(monkeypatch):
     """More threads than cores call one backend at once, with a short
     switch interval; each must get its own batch's products, as a backend

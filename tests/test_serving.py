@@ -624,6 +624,136 @@ def test_encode_batch_refuses_mixed_shapes():
         ExecutionBackend._encode_batch(code, [As[0][:, :10]], [Bs[0][:10]])
 
 
+def _encode_jobs(sizes, seed, rows=12, inner=40, cols=6):
+    rng = np.random.default_rng(seed)
+    return [([rng.standard_normal((rows, inner)) for _ in range(n)],
+             [rng.standard_normal((inner, cols)) for _ in range(n)])
+            for n in sizes]
+
+
+@pytest.mark.parametrize("n_shards", [None, N - 5])
+@pytest.mark.parametrize("family", sorted(ENCODE_CODES))
+def test_encode_batch_bit_identical_through_reused_buffers(
+        family, n_shards, encode_buffers):
+    """Batches of 4, 1, 3 and 4 requests, each handed back: the first
+    grows the buffers and the rest encode into prefixes of the same
+    memory, and every batch equals a fresh ``CDCCode.encode`` per
+    request, bit for bit."""
+    from repro.serving.backends import ExecutionBackend
+    code = ENCODE_CODES[family]()
+    bases = set()
+    for As, Bs in _encode_jobs((4, 1, 3, 4), seed=11):
+        E_A, E_B = ExecutionBackend._encode_batch(code, As, Bs, n_shards)
+        assert E_A.flags.c_contiguous and E_B.flags.c_contiguous
+        for r, (A, B) in enumerate(zip(As, Bs)):
+            want = code.encode(*split_contraction(A, B, K),
+                               n_shards=n_shards)
+            np.testing.assert_array_equal(E_A[r], want[0])
+            np.testing.assert_array_equal(E_B[r], want[1])
+        bases.add((id(E_A.base), id(E_B.base)))
+        encode_buffers.give_back(E_A=E_A, E_B=E_B)
+    assert len(bases) == 1
+
+
+@pytest.mark.parametrize("keeper", ["encode", "simulated"])
+def test_encode_output_a_caller_keeps_is_never_overwritten(keeper,
+                                                           encode_buffers):
+    """A caller that never hands its ``E_A`` and ``E_B`` back (a cluster
+    dispatch publishing them, the simulated and replay backends) owns
+    them: batches that hand theirs back, and later kept batches, write
+    other memory, and each kept batch still holds its own encode."""
+    from repro.serving import DeviceBackend
+    from repro.serving.backends import ExecutionBackend
+    code = ENCODE_CODES["gsac_complex"]()
+    device = DeviceBackend(use_pallas=False)
+    kept, products = [], []
+    for i, (As, Bs) in enumerate(_encode_jobs((4, 4, 1, 3, 4), seed=12)):
+        if keeper == "encode":
+            E_A, E_B = ExecutionBackend._encode_batch(code, As, Bs)
+        else:
+            P = SimulatedBackend().compute_products(code, As, Bs)
+            products.append((P, P.copy()))
+            E_A, E_B = SimulatedBackend._encode_batch(code, As, Bs)
+        for earlier in kept:
+            assert not np.shares_memory(earlier[0], E_A)
+            assert not np.shares_memory(earlier[1], E_B)
+        kept.append((E_A, E_B, E_A.copy(), E_B.copy()))
+        device.compute_products(code, *_encode_jobs((4 - i % 2,), 13 + i)[0])
+    for E_A, E_B, A_copy, B_copy in kept:
+        np.testing.assert_array_equal(E_A, A_copy)
+        np.testing.assert_array_equal(E_B, B_copy)
+    for P, P_copy in products:
+        np.testing.assert_array_equal(P, P_copy)
+
+
+def test_encode_buffers_take_back_only_what_they_lent(encode_buffers):
+    """``give_back`` ignores arrays the pool did not lend under that role,
+    so an override's own arrays never enter it, and a lent buffer goes
+    back once."""
+    own = np.zeros((2, 3))
+    encode_buffers.give_back(E_A=own, E_B=own.reshape(-1)[:4])
+    lent = encode_buffers.take("E_A", (2, 3), np.float64)
+    encode_buffers.give_back(E_B=lent)               # not its role
+    assert not np.shares_memory(encode_buffers.take("E_A", (2, 3),
+                                                    np.float64), own)
+    encode_buffers.give_back(E_A=lent)
+    encode_buffers.give_back(E_A=lent)               # already back
+    again = encode_buffers.take("E_A", (1, 3), np.float64)
+    assert again.base is lent.base
+    assert not np.shares_memory(encode_buffers.take("E_A", (1, 3),
+                                                    np.float64), lent)
+
+
+def test_backend_shared_across_threads_with_kept_encodes(encode_buffers):
+    """More threads than cores: some serve batches through one shared
+    ``DeviceBackend`` (which hands its encode back), the rest keep their
+    encodes.  Each device batch gets its own products and no kept encode
+    is overwritten."""
+    import os
+    import sys
+    import threading
+
+    from repro.serving import DeviceBackend
+    from repro.serving.backends import ExecutionBackend
+    code = ENCODE_CODES["gsac_complex"]()
+    n_threads = 2 * len(os.sched_getaffinity(0)) + 1
+    jobs = [_encode_jobs((1 + i % 4,), seed=200 + i, rows=8, inner=16,
+                         cols=4)[0] for i in range(n_threads)]
+    want = [DeviceBackend(use_pallas=False).compute_products(code, *j)
+            if i % 2 == 0 else None for i, j in enumerate(jobs)]
+    shared = DeviceBackend(use_pallas=False)
+    got = [None] * n_threads
+    kept = [[] for _ in range(n_threads)]
+
+    def work(i):
+        for _ in range(3):
+            if i % 2 == 0:
+                got[i] = shared.compute_products(code, *jobs[i])
+            else:
+                E = ExecutionBackend._encode_batch(code, *jobs[i])
+                kept[i].append((E, [e.copy() for e in E]))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    for i in range(0, n_threads, 2):
+        np.testing.assert_array_equal(got[i], want[i])
+    for i in range(1, n_threads, 2):
+        assert len(kept[i]) == 3
+        for E, copies in kept[i]:
+            for e, c in zip(E, copies):
+                np.testing.assert_array_equal(e, c)
+
+
 def test_device_backend_encodes_once_per_batch_through_the_hook():
     """``compute_products`` encodes through ``self._encode_batch``, once a
     batch, so a subclass that wraps the hook (a timer) sees every encode."""
