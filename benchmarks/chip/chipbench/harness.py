@@ -13,7 +13,7 @@ import numpy as np
 
 from . import arrivals, spec
 from .check import compare
-from .operands import derived_pair, make_operands
+from .operands import derived_pair, fixed_operand, make_operands
 
 CACHE_DIR = os.path.join(spec.ROOT, ".jax_cache")
 TRACE_DIR = os.path.join(spec.ROOT, ".chipbench_trace")
@@ -136,12 +136,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     skips the look for a chip, for the tests on the CPU.  ``traffic``
     replaces the cell's traffic mix (the knee sweep), and ``readings``, a
     dict, receives every number of the comparison, compared or not, and
-    the :class:`Run` under ``run``.
+    the :class:`Run` under ``run``.  The configuration's ``job`` may fix
+    ``B`` (:mod:`chipbench.operands`); any other ``fixed`` value raises
+    ``ValueError`` here, before anything is drawn or built.
     """
     t_start = time.perf_counter() if t_start is None else t_start
     bench = spec.load_benchmark()
     cell = spec.find_cell(bench, workload)
     cfg = spec.load_config(bench, cell["config"])
+    fixed = fixed_operand(cfg["job"])
     cmod = spec.config_module(bench, cell["config"])
     if traffic is None:
         traffic = spec.load_traffic(cell["traffic"])
@@ -177,7 +180,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     sizes = warm_sizes(traffic, batch_size)
     n_req = _request_count(traffic, schedule, seconds)
     drawn = make_operands(seed, n_req + max(sizes), job["rows"],
-                          job["inner"], job["cols"])
+                          job["inner"], job["cols"], fixed)
     warm, pairs = drawn[:max(sizes)], drawn[max(sizes):]
     for size in sizes:
         server.serve(warm[:size])
@@ -193,7 +196,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         opts.host_tracer_level = 1
         opts.python_tracer_level = 0
         jax.profiler.start_trace(TRACE_DIR, profiler_options=opts)
-    window = pace(server, pairs, traffic, schedule, seconds, batch_size)
+    window = pace(server, pairs, traffic, schedule, seconds, batch_size,
+                  fixed)
     tr = None
     if trace:
         jax.profiler.stop_trace()
@@ -213,7 +217,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     run = Run(cell, cfg, traffic, seed, seconds, setup_s, window,
               device["kind"], tr, launches)
     numbers, checks, failed = compare(
-        run.answered, lambda r: derived_pair(pairs, r.index),
+        run.answered, lambda r: derived_pair(pairs, r.index, fixed),
         cmod.reference, cfg, limits)
     if readings is not None:
         readings.update(numbers, run=run)

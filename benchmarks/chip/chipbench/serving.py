@@ -201,7 +201,7 @@ class Server:
 
 
 def pace(server: Server, pairs, traffic: dict, arrivals, seconds: float,
-         batch_size: int) -> Window:
+         batch_size: int, fixed: str | None = None) -> Window:
     """Drive one measured window.
 
     Open loop: the requests of ``arrivals`` (seconds from the window's
@@ -209,7 +209,8 @@ def pace(server: Server, pairs, traffic: dict, arrivals, seconds: float,
     window's end too, so that each due request's latency counts.  Closed
     loop: ``traffic["outstanding"]`` clients each send a request as soon as
     their previous one is answered; no batch starts after ``seconds``.
-    The window lasts until its last answer.
+    The window lasts until its last answer.  Request ``i`` is served
+    ``derived_pair(pairs, i, fixed)``.
     """
     closed = traffic["loop"] == "closed"
     win = Window()
@@ -241,7 +242,7 @@ def pace(server: Server, pairs, traffic: dict, arrivals, seconds: float,
             break
         take = [due.popleft() for _ in range(min(batch_size, len(due)))]
         answers, rec = server.timed_batch(
-            [derived_pair(pairs, r.index) for r in take], clock, t0)
+            [derived_pair(pairs, r.index, fixed) for r in take], clock, t0)
         for r, (est, m, order) in zip(take, answers):
             r.handoff, r.answered = rec.handoff, rec.answered
             r.estimate, r.m, r.order = est, m, order
